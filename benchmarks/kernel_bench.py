@@ -290,6 +290,8 @@ def run(quick: bool = False, check: bool = False, autotune: bool = False,
     payload = {
         "meta": {
             "backend": jax.default_backend(),
+            # kernels/tuning.py applies the autotune table only on this kind
+            "device_kind": jax.devices()[0].device_kind,
             "execution": "native" if on_tpu else "interpret",
             "note": ("off-TPU the Pallas kernels run in interpret mode, "
                      "which prices grid steps rather than HBM traffic; "

@@ -85,6 +85,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.configs.base import ModelConfig
 from repro.configs.registry import build_model
+from repro.kernels.ops import partition_mesh
 from repro.kernels.tuning import host_cu_blocks
 
 if TYPE_CHECKING:  # real import is lazy: serving/__init__ imports back here
@@ -396,11 +397,16 @@ class SpecDecodeEngine:
             paged_fused=self.tcfg.paged_fused, cu_arg=cu_arg,
             src_file=code.co_filename, src_line=code.co_firstlineno)
 
+        # a jit compiled for the serving mesh traces its kernels per device
+        # (XLA cannot partition a Pallas TPU kernel; kernels/ops.py)
+        mesh = self.mesh if in_shardings is not None else None
+
         @wraps(fn)
         def counted(*args, **kwargs):
             entry.n_traces += 1
             entry.arg_specs = jax.tree.map(_trace_spec, args)
-            out = fn(*args, **kwargs)
+            with partition_mesh(mesh):
+                out = fn(*args, **kwargs)
             entry.out_specs = jax.tree.map(_trace_spec, out)
             return out
 

@@ -5,7 +5,7 @@ Each kernel module pairs a Pallas body with a pure-jnp reference in
 check against: ``spec_verify_attn`` (the batched s-token verify
 attention), ``paged_verify_attn`` (the fused variant that streams KV
 through the scalar-prefetched block table — no materialized gather),
-``flash_attn``, ``rmsnorm``, and ``ssd_chunk``.  ``ops.py`` is the
+``rmsnorm``, and ``ssd_chunk``.  ``ops.py`` is the
 dispatch layer (``kernel_mode``) that picks kernel vs reference.
 
 BlockSpec index maps in this package are pure block-address arithmetic

@@ -6,23 +6,30 @@ kernel body in Python for bit-faithful validation) or fall back to the
 pure-jnp reference (fast path for CPU benchmarks).  Models call these
 wrappers, so swapping the implementation never touches model code.
 
-GQA head folding: the attention kernels operate on one kv-head per grid row.
-``flash_attn``/``spec_verify_attn`` fold (batch, kv_head) into the kernel
-batch dim and the q-head group into the q rows, so a 32-head/4-kv-head GQA
-layer becomes 4 kernel batches of 8x-longer q blocks — dense MXU tiles
-instead of 8 strided passes.
+GQA head folding: the attention kernel operates on one kv-head per grid row.
+``spec_verify_attn`` folds (batch, kv_head) into the kernel batch dim and
+the q-head group into the q rows, so a 32-head/4-kv-head GQA layer becomes
+4 kernel batches of 8x-longer q blocks — dense MXU tiles instead of 8
+strided passes.
+
+Meshes: XLA cannot partition a Pallas TPU kernel, so a jit compiled for a
+multi-device mesh must run each kernel inside ``shard_map``.  The engine
+traces its sharded jits under :func:`partition_mesh`, and
+:func:`batch_partitioned` then runs the verify kernel per device on its
+slice of the batch (or on the whole batch, replicated, where the batch
+does not divide over the data axes — the B=1 prefill forwards).
 """
 from __future__ import annotations
 
-import functools
+import contextlib
 import math
-from typing import Optional, Tuple
+from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref as _ref
-from repro.kernels.flash_attn import flash_attn_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.spec_verify_attn import spec_verify_attn_pallas
 from repro.kernels.ssd_chunk import ssd_chunk_pallas
@@ -45,6 +52,39 @@ def _mode(use_pallas: Optional[bool]) -> str:
 # the exact same policy (None -> native on TPU / reference on CPU;
 # True -> native on TPU / interpret elsewhere; False -> reference)
 kernel_mode = _mode
+
+
+# ---------------------------------------------------------------------------
+# kernels under a mesh
+
+_MESH: list = []
+
+
+@contextlib.contextmanager
+def partition_mesh(mesh):
+    """Trace the enclosed code for ``mesh`` (None: a single device): kernel
+    calls made while it is active run per device (:func:`batch_partitioned`)."""
+    _MESH.append(mesh)
+    try:
+        yield
+    finally:
+        _MESH.pop()
+
+
+def batch_partitioned(fn: Callable, batched: Sequence) -> jax.Array:
+    """``fn(*batched)``, run inside ``shard_map`` when a partition mesh is
+    active.  Every operand and the result lead with the batch axis, and a
+    kernel row reads only its own batch entry, so each device computes its
+    slice of the batch over the mesh's data axes; a batch that does not
+    divide over them (B=1 prefill) is computed whole on every device."""
+    mesh = _MESH[-1] if _MESH else None
+    if mesh is None or mesh.size == 1:
+        return fn(*batched)
+    axes = tuple(a for a in mesh.axis_names if a in ("pod", "data"))
+    n = math.prod(mesh.shape[a] for a in axes)
+    spec = P(axes) if axes and batched[0].shape[0] % n == 0 else P()
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * len(batched),
+                         out_specs=spec, check_vma=False)(*batched)
 
 
 # ---------------------------------------------------------------------------
@@ -92,30 +132,6 @@ def _fold_gqa(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# flash attention (training / prefill)
-
-
-def flash_attn(q: jax.Array, k: jax.Array, v: jax.Array,
-               q_pos: jax.Array, k_pos: jax.Array,
-               window: Optional[int] = None, prefix_len: int = 0,
-               scale: Optional[float] = None,
-               block_q: int = 512, block_k: int = 512,
-               use_pallas: Optional[bool] = None) -> jax.Array:
-    """GQA flash attention.  q: [B,T,H,hd]; k/v: [B,L,KVH,hd];
-    q_pos/k_pos: [B,T]/[B,L].  Returns [B,T,H,vd]."""
-    m = _mode(use_pallas)
-    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    if m == "ref":
-        # unfolded layout: keeps the model-axis sharding of q/k/v intact
-        return _ref.gqa_masked_ref(q, k, v, q_pos, k_pos, window, prefix_len,
-                                   scale)
-    qf, kf, vf, qpf, kpf, unfold = _fold_gqa(q, k, v, q_pos, k_pos)
-    o = flash_attn_pallas(qf, kf, vf, qpf, kpf, window, prefix_len, scale,
-                          block_q, block_k, interpret=(m == "interpret"))
-    return unfold(o)
-
-
-# ---------------------------------------------------------------------------
 # speculative verify attention (decode hot path)
 
 
@@ -126,8 +142,9 @@ def spec_verify_attn(q: jax.Array, k: jax.Array, v: jax.Array,
                      k_scale: Optional[jax.Array] = None,
                      v_scale: Optional[jax.Array] = None,
                      use_pallas: Optional[bool] = None) -> jax.Array:
-    """Verify-step attention.  Same shapes as :func:`flash_attn` with tiny T
-    (s+1); q rows are padded to a multiple of 8 for TPU sublanes, padded rows
+    """Verify-step attention.  q: [B,T,H,hd] with tiny T (s+1, or a prefill
+    chunk); k/v: [B,L,KVH,hd]; q_pos/k_pos: [B,T]/[B,L]; returns
+    [B,T,H,hd].  q rows are padded to a multiple of 8 for TPU sublanes, padded rows
     carry q_pos = -1 and are sliced off the output.
 
     int8 caches (kv_quant): pass the int8 k/v plus per-(row, kv-head)
@@ -146,23 +163,28 @@ def spec_verify_attn(q: jax.Array, k: jax.Array, v: jax.Array,
         # unfolded layout: keeps the model-axis sharding of the cache intact
         return _ref.gqa_masked_ref(q, k, v, q_pos, k_pos, window, prefix_len,
                                    scale)
-    qf, kf, vf, qpf, kpf, unfold = _fold_gqa(q, k, v, q_pos, k_pos)
-    ksf = vsf = None
-    if k_scale is not None:
-        B, L, KVH = k_scale.shape
-        ksf = k_scale.transpose(0, 2, 1).reshape(B * KVH, L)
-        vsf = v_scale.transpose(0, 2, 1).reshape(B * KVH, L)
-    rows = qf.shape[1]
-    pad = (-rows) % 8
-    if pad:
-        qf = jnp.pad(qf, ((0, 0), (0, pad), (0, 0)))
-        qpf = jnp.pad(qpf, ((0, 0), (0, pad)), constant_values=-1)
-    o = spec_verify_attn_pallas(qf, kf, vf, qpf, kpf, window, prefix_len,
-                                scale, block_k, k_scale=ksf, v_scale=vsf,
-                                interpret=(m == "interpret"))
-    if pad:
-        o = o[:, :rows]
-    return unfold(o)
+
+    def kernel(q, k, v, q_pos, k_pos, k_scale=None, v_scale=None):
+        qf, kf, vf, qpf, kpf, unfold = _fold_gqa(q, k, v, q_pos, k_pos)
+        ksf = vsf = None
+        if k_scale is not None:
+            B, L, KVH = k_scale.shape
+            ksf = k_scale.transpose(0, 2, 1).reshape(B * KVH, L)
+            vsf = v_scale.transpose(0, 2, 1).reshape(B * KVH, L)
+        rows = qf.shape[1]
+        pad = (-rows) % 8
+        if pad:
+            qf = jnp.pad(qf, ((0, 0), (0, pad), (0, 0)))
+            qpf = jnp.pad(qpf, ((0, 0), (0, pad)), constant_values=-1)
+        o = spec_verify_attn_pallas(qf, kf, vf, qpf, kpf, window, prefix_len,
+                                    scale, block_k, k_scale=ksf, v_scale=vsf,
+                                    interpret=(m == "interpret"))
+        if pad:
+            o = o[:, :rows]
+        return unfold(o)
+
+    scales = () if k_scale is None else (k_scale, v_scale)
+    return batch_partitioned(kernel, (q, k, v, q_pos, k_pos, *scales))
 
 
 # ---------------------------------------------------------------------------

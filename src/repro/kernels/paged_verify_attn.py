@@ -38,17 +38,25 @@ Two grid strategies over the same tile math:
   raggedness costs nothing.
 
 The ragged kernel additionally offers **explicit multi-buffered DMA**
-(``num_buffers >= 2``): k/v/pos (and int8 scale) pool tiles live in
-``ANY`` memory space and the kernel drives its own ``make_async_copy``
-ring — ``num_buffers`` VMEM landing buffers per stream, one DMA semaphore
-lane each, warm-up fetch of the first ``num_buffers - 1`` tiles at step 0
-and a steady-state fetch of tile ``i + num_buffers - 1`` each step — so
-the fetch horizon (how far DMA runs ahead of compute) is a tunable knob
-instead of the pipeline default.  ``num_buffers = 0`` keeps the standard
+(``num_buffers >= 2``): the k/v pool tiles live in ``ANY`` memory space
+and the kernel drives its own ``make_async_copy`` ring — ``num_buffers``
+VMEM landing buffers per stream, one DMA semaphore lane each, warm-up
+fetch of the first ``num_buffers - 1`` tiles at step 0 and a steady-state
+fetch of tile ``i + num_buffers - 1`` each step — so the fetch horizon
+(how far DMA runs ahead of compute) is a tunable knob instead of the
+pipeline default.  The small pos (and int8 scale) tiles keep riding the
+BlockSpec pipeline.  ``num_buffers = 0`` keeps the standard
 BlockSpec auto-pipeline.  ``profile='dma'`` / ``profile='compute'`` skip
 the compute or the copies respectively so the benchmark can price the two
 halves of the pipeline separately (benchmarks/kernel_bench.py
 ``--profile-dma``).
+
+TPU block layout: every block's last two dimensions must be multiples of
+(8, 128) or span the whole array axis.  So the position maps travel as
+``[B, GT, 1]`` (query positions, a column per slot) and ``[NB, 1, bs]``
+(pool positions, a row per block), and the online-softmax max/sum
+scratch is ``[KVH, GT, 1]``: the score tile ``[GT, bs]`` is then masked by
+a plain column-vs-row broadcast, with no relayout in the kernel.
 
 Masking (q_pos/k_pos arithmetic, ``window``, ``prefix_len``) is the shared
 position-mask contract of kernels/ref.py, evaluated against the pool's
@@ -92,31 +100,32 @@ def _flash_tile(q_ref, kt, vt, qp, kp, acc_ref, m_ref, l_ref, *,
 
     ``kt``/``vt`` are tile *values* (read from a BlockSpec ref or a manual
     DMA landing buffer); ``ks``/``vs`` are the int8 dequant scale tiles
-    ``[bs, KVH]`` when the pool is quantized.
+    ``[bs, KVH]`` when the pool is quantized.  ``qp`` is the ``[GT, 1]``
+    query-position column, ``kp`` the ``[1, bs]`` pool-position row.
     """
-    ok = (kp[None, :] >= 0) & (kp[None, :] <= qp[:, None])   # [GT, bs]
+    ok = (kp >= 0) & (kp <= qp)                          # [GT, bs]
     if window is not None:
-        ok &= kp[None, :] > qp[:, None] - window
+        ok &= kp > qp - window
     if prefix_len:
-        ok |= (kp[None, :] >= 0) & (kp[None, :] < prefix_len)
+        ok |= (kp >= 0) & (kp < prefix_len)
     for h in range(kvh):                             # unrolled 2D dots
         q = q_ref[0, h].astype(jnp.float32)          # [GT, hd]
         k = kt[:, h, :].astype(jnp.float32)          # [bs, hd]
         v = vt[:, h, :].astype(jnp.float32)
         if ks is not None:
             # int8 pool tiles: moved at 1 B/elem, dequantized in VMEM
-            k = k * ks[:, h].astype(jnp.float32)[:, None]
-            v = v * vs[:, h].astype(jnp.float32)[:, None]
+            k = k * ks[:, h:h + 1].astype(jnp.float32)
+            v = v * vs[:, h:h + 1].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
         s = jnp.where(ok, s, -jnp.inf)
-        m_prev = m_ref[h]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
+        m_prev = m_ref[h]                                # [GT, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
-        p = jnp.where(ok, jnp.exp(s - m_safe[:, None]), 0.0)
+        p = jnp.where(ok, jnp.exp(s - m_safe), 0.0)
         corr = jnp.where(jnp.isneginf(m_prev), 0.0,
                          jnp.exp(m_prev - m_safe))
-        l_ref[h] = l_ref[h] * corr + p.sum(axis=-1)
-        acc_ref[h] = acc_ref[h] * corr[:, None] + jax.lax.dot_general(
+        l_ref[h] = l_ref[h] * corr + p.sum(axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())))
         m_ref[h] = m_new
 
@@ -124,7 +133,8 @@ def _flash_tile(q_ref, kt, vt, qp, kp, acc_ref, m_ref, l_ref, *,
 def _tile_visible(qp, kp, window: Optional[int], prefix_len: int):
     """Tile-level visibility (flash-decode early exit): is any pool row in
     this tile attendable by any query?  Dead tiles report False outright —
-    identical to every row carrying position -1."""
+    identical to every row carrying position -1.  ``qp`` is ``[GT, 1]``,
+    ``kp`` is ``[1, bs]``."""
     q_hi = qp.max()
     vis = (kp >= 0) & (kp <= q_hi)
     if window is not None:
@@ -140,8 +150,8 @@ def _fold_q(q: jax.Array, q_pos: jax.Array, kvh: int):
     (g, t), matching ops._fold_gqa's ordering), repeat q_pos per group
     row, and pad the row dim to the TPU sublane multiple (8) with
     ``q_pos = -1`` rows that match nothing.  Returns ``(qf, qpf, GT,
-    unfold)`` where unfold maps ``[B, KVH, GT, hd]`` back to
-    ``[B, T, H, hd]``.
+    unfold)``: ``qpf`` is the ``[B, GT, 1]`` position column, and unfold
+    maps ``[B, KVH, GT, hd]`` back to ``[B, T, H, hd]``.
     """
     B, T, H, hd = q.shape
     G = H // kvh
@@ -154,6 +164,7 @@ def _fold_q(q: jax.Array, q_pos: jax.Array, kvh: int):
         qf = jnp.pad(qf, ((0, 0), (0, 0), (0, pad), (0, 0)))
         qpf = jnp.pad(qpf, ((0, 0), (0, pad)), constant_values=-1)
     GT = rows + pad
+    qpf = qpf[:, :, None]
 
     def unfold(o: jax.Array) -> jax.Array:
         ot = o[:, :, :rows] if pad else o
@@ -161,6 +172,13 @@ def _fold_q(q: jax.Array, q_pos: jax.Array, kvh: int):
                   .reshape(B, T, H, hd))
 
     return qf, qpf, GT, unfold
+
+
+def _acc_scratch(kvh: int, gt: int, hd: int):
+    """Online-softmax VMEM scratch: accumulator, running max, running sum."""
+    return [pltpu.VMEM((kvh, gt, hd), jnp.float32),
+            pltpu.VMEM((kvh, gt, 1), jnp.float32),
+            pltpu.VMEM((kvh, gt, 1), jnp.float32)]
 
 
 # ---------------------------------------------------------------------------
@@ -184,8 +202,8 @@ def _fused_kernel(bt_ref, q_ref, k_ref, v_ref, qp_ref, pp_ref, *rest,
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    qp = qp_ref[0]                                       # [GT]
-    kp = pp_ref[0]                                       # [bs]
+    qp = qp_ref[0]                                       # [GT, 1]
+    kp = pp_ref[0]                                       # [1, bs]
     owned = bt_ref[b, j] >= 0
 
     @pl.when(owned & _tile_visible(qp, kp, window, prefix_len))
@@ -198,8 +216,7 @@ def _fused_kernel(bt_ref, q_ref, k_ref, v_ref, qp_ref, pp_ref, *rest,
 
     @pl.when(j == nb - 1)
     def _finish():
-        o_ref[0] = (acc_ref[...]
-                    / jnp.maximum(l_ref[...], 1e-30)[..., None]
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
                     ).astype(o_ref.dtype)
 
 
@@ -238,7 +255,7 @@ def paged_verify_attn_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         return (jnp.maximum(bt[b, j], 0), 0, 0, 0)
 
     def _pos_map(b, j, bt):
-        return (jnp.maximum(bt[b, j], 0), 0)
+        return (jnp.maximum(bt[b, j], 0), 0, 0)
 
     def _scale_map(b, j, bt):
         return (jnp.maximum(bt[b, j], 0), 0, 0)
@@ -248,10 +265,10 @@ def paged_verify_attn_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         pl.BlockSpec((1, KVH, GT, hd), lambda b, j, bt: (b, 0, 0, 0)),
         pl.BlockSpec((1, bs, KVH, hd), _kv_map),
         pl.BlockSpec((1, bs, KVH, hd), _kv_map),
-        pl.BlockSpec((1, GT), lambda b, j, bt: (b, 0)),
-        pl.BlockSpec((1, bs), _pos_map),
+        pl.BlockSpec((1, GT, 1), lambda b, j, bt: (b, 0, 0)),
+        pl.BlockSpec((1, 1, bs), _pos_map),
     ]
-    args = [block_tables, qf, k, v, qpf, pos]
+    args = [block_tables, qf, k, v, qpf, pos[:, None, :]]
     if quant:
         in_specs += [pl.BlockSpec((1, bs, KVH), _scale_map),
                      pl.BlockSpec((1, bs, KVH), _scale_map)]
@@ -265,11 +282,7 @@ def paged_verify_attn_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, KVH, GT, hd),
                                lambda b, j, bt: (b, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((KVH, GT, hd), jnp.float32),
-            pltpu.VMEM((KVH, GT), jnp.float32),
-            pltpu.VMEM((KVH, GT), jnp.float32),
-        ],
+        scratch_shapes=_acc_scratch(KVH, GT, hd),
     )
     o = pl.pallas_call(
         kern,
@@ -334,8 +347,8 @@ def _ragged_kernel(bt_ref, ss_ref, sb_ref, cu_ref, q_ref, k_ref, v_ref,
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    qp = qp_ref[0]                                       # [GT]
-    kp = pp_ref[0]                                       # [bs]
+    qp = qp_ref[0]                                       # [GT, 1]
+    kp = pp_ref[0]                                       # [1, bs]
     owned = bt_ref[b, sb_ref[i]] >= 0
 
     if profile != "dma":
@@ -349,50 +362,43 @@ def _ragged_kernel(bt_ref, ss_ref, sb_ref, cu_ref, q_ref, k_ref, v_ref,
 
     @pl.when(i == cu_ref[b + 1] - 1)
     def _finish():
-        o_ref[0] = (acc_ref[...]
-                    / jnp.maximum(l_ref[...], 1e-30)[..., None]
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
                     ).astype(o_ref.dtype)
 
 
 def _ragged_dma_kernel(bt_ref, ss_ref, sb_ref, cu_ref, pbs_ref, q_ref,
-                       qp_ref, k_hbm, v_hbm, pp_hbm, *rest,
+                       qp_ref, pp_ref, *rest,
                        scale: float, window: Optional[int], prefix_len: int,
                        kvh: int, quant: bool, nbuf: int,
                        profile: Optional[str]):
     """Ragged grid with an explicit ``nbuf``-deep manual DMA ring.
 
-    k/v/pos (and int8 scale) pools stay in ANY memory space; each stream
-    gets ``nbuf`` VMEM landing buffers and a DMA semaphore lane per
-    buffer.  Step 0 warm-starts the first ``nbuf - 1`` tile fetches; every
-    step then starts tile ``i + nbuf - 1`` and waits on its own —
-    generalized double/quad buffering with the fetch horizon as a knob.
+    The k/v pools stay in ANY memory space; each gets ``nbuf`` VMEM
+    landing buffers and a DMA semaphore lane per buffer.  Step 0
+    warm-starts the first ``nbuf - 1`` tile fetches; every step then
+    starts tile ``i + nbuf - 1`` and waits on its own — generalized
+    double/quad buffering with the fetch horizon as a knob.  The pos (and
+    int8 scale) tiles are a few hundred bytes per block, and their
+    sub-128-lane rows are not a legal manual-DMA slice on the TPU, so they
+    ride the BlockSpec pipeline through the same ``pbs`` block map.
     """
     if quant:
-        (ks_hbm, vs_hbm, o_ref, acc_ref, m_ref, l_ref,
-         kbuf, vbuf, pbuf, ksbuf, vsbuf,
-         ksem, vsem, psem, kssem, vssem) = rest
+        (ks_ref, vs_ref, k_hbm, v_hbm, o_ref, acc_ref, m_ref, l_ref,
+         kbuf, vbuf, ksem, vsem) = rest
     else:
-        (o_ref, acc_ref, m_ref, l_ref, kbuf, vbuf, pbuf,
-         ksem, vsem, psem) = rest
-        ks_hbm = vs_hbm = ksbuf = vsbuf = kssem = vssem = None
+        (k_hbm, v_hbm, o_ref, acc_ref, m_ref, l_ref,
+         kbuf, vbuf, ksem, vsem) = rest
+        ks_ref = vs_ref = None
     i = pl.program_id(0)
     n = pl.num_programs(0)
     b = ss_ref[i]
 
     def _copies(t, slot):
         blk = pbs_ref[t]
-        ops = [pltpu.make_async_copy(k_hbm.at[blk], kbuf.at[slot],
-                                     ksem.at[slot]),
-               pltpu.make_async_copy(v_hbm.at[blk], vbuf.at[slot],
-                                     vsem.at[slot]),
-               pltpu.make_async_copy(pp_hbm.at[blk], pbuf.at[slot],
-                                     psem.at[slot])]
-        if quant:
-            ops += [pltpu.make_async_copy(ks_hbm.at[blk], ksbuf.at[slot],
-                                          kssem.at[slot]),
-                    pltpu.make_async_copy(vs_hbm.at[blk], vsbuf.at[slot],
-                                          vssem.at[slot])]
-        return ops
+        return [pltpu.make_async_copy(k_hbm.at[blk], kbuf.at[slot],
+                                      ksem.at[slot]),
+                pltpu.make_async_copy(v_hbm.at[blk], vbuf.at[slot],
+                                      vsem.at[slot])]
 
     @pl.when(i == cu_ref[b])
     def _init():
@@ -422,8 +428,8 @@ def _ragged_dma_kernel(bt_ref, ss_ref, sb_ref, cu_ref, pbs_ref, q_ref,
         for op in _copies(i, slot):
             op.wait()
 
-    qp = qp_ref[0]                                       # [GT]
-    kp = pbuf[slot]                                      # [bs]
+    qp = qp_ref[0]                                       # [GT, 1]
+    kp = pp_ref[0]                                       # [1, bs]
     owned = bt_ref[b, sb_ref[i]] >= 0
 
     if profile != "dma":
@@ -432,13 +438,12 @@ def _ragged_dma_kernel(bt_ref, ss_ref, sb_ref, cu_ref, pbs_ref, q_ref,
             _flash_tile(q_ref, kbuf[slot], vbuf[slot], qp, kp,
                         acc_ref, m_ref, l_ref, scale=scale, window=window,
                         prefix_len=prefix_len, kvh=kvh,
-                        ks=None if ksbuf is None else ksbuf[slot],
-                        vs=None if vsbuf is None else vsbuf[slot])
+                        ks=None if ks_ref is None else ks_ref[0],
+                        vs=None if vs_ref is None else vs_ref[0])
 
     @pl.when(i == cu_ref[b + 1] - 1)
     def _finish():
-        o_ref[0] = (acc_ref[...]
-                    / jnp.maximum(l_ref[...], 1e-30)[..., None]
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
                     ).astype(o_ref.dtype)
 
 
@@ -472,7 +477,7 @@ def ragged_paged_verify_attn_pallas(q: jax.Array, k: jax.Array,
 
     * ``num_buffers = 0`` — standard BlockSpec auto-pipelining;
       ``>= 2`` — explicit manual DMA with that many landing buffers per
-      k/v/pos(/scale) stream (double/quad/... buffering).
+      k/v stream (double/quad/... buffering).
     * ``vmem_limit_bytes`` — TPU compiler VMEM budget for the launch
       (ignored in interpret mode).
     * ``profile`` — ``'dma'`` skips the tile compute, ``'compute'`` skips
@@ -493,46 +498,48 @@ def ragged_paged_verify_attn_pallas(q: jax.Array, k: jax.Array,
         return (ss[i], 0, 0, 0)
 
     def _qp_map(i, bt, ss, sb, cu):
-        return (ss[i], 0)
+        return (ss[i], 0, 0)
 
     kwargs = {}
     if vmem_limit_bytes is not None and not interpret:
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=int(vmem_limit_bytes))
+    pos3 = pos[:, None, :]                               # [NB, 1, bs] rows
 
     if num_buffers >= 2:
         def _q_map_d(i, bt, ss, sb, cu, pbs):
             return (ss[i], 0, 0, 0)
 
         def _qp_map_d(i, bt, ss, sb, cu, pbs):
-            return (ss[i], 0)
+            return (ss[i], 0, 0)
+
+        def _pos_map_d(i, bt, ss, sb, cu, pbs):
+            return (pbs[i], 0, 0)
+
+        def _scale_map_d(i, bt, ss, sb, cu, pbs):
+            return (pbs[i], 0, 0)
 
         in_specs = [
             pl.BlockSpec((1, KVH, GT, hd), _q_map_d),
-            pl.BlockSpec((1, GT), _qp_map_d),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec((1, GT, 1), _qp_map_d),
+            pl.BlockSpec((1, 1, bs), _pos_map_d),
         ]
         args = [block_tables, ss, sb, cu_blocks.astype(jnp.int32), pbs,
-                qf, qpf, k, v, pos]
+                qf, qpf, pos3]
         if quant:
-            in_specs += [pl.BlockSpec(memory_space=pltpu.ANY),
-                         pl.BlockSpec(memory_space=pltpu.ANY)]
+            in_specs += [pl.BlockSpec((1, bs, KVH), _scale_map_d),
+                         pl.BlockSpec((1, bs, KVH), _scale_map_d)]
             args += [k_scale, v_scale]
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY),
+                     pl.BlockSpec(memory_space=pl.ANY)]
+        args += [k, v]
         D = num_buffers
-        scratch = [
-            pltpu.VMEM((KVH, GT, hd), jnp.float32),
-            pltpu.VMEM((KVH, GT), jnp.float32),
-            pltpu.VMEM((KVH, GT), jnp.float32),
+        scratch = _acc_scratch(KVH, GT, hd) + [
             pltpu.VMEM((D, bs, KVH, hd), k.dtype),
             pltpu.VMEM((D, bs, KVH, hd), v.dtype),
-            pltpu.VMEM((D, bs), pos.dtype),
+            pltpu.SemaphoreType.DMA((D,)),
+            pltpu.SemaphoreType.DMA((D,)),
         ]
-        if quant:
-            scratch += [pltpu.VMEM((D, bs, KVH), k_scale.dtype),
-                        pltpu.VMEM((D, bs, KVH), v_scale.dtype)]
-        scratch += [pltpu.SemaphoreType.DMA((D,))] * (5 if quant else 3)
         kern = functools.partial(_ragged_dma_kernel, scale=scale,
                                  window=window, prefix_len=prefix_len,
                                  kvh=KVH, quant=quant, nbuf=D,
@@ -549,7 +556,7 @@ def ragged_paged_verify_attn_pallas(q: jax.Array, k: jax.Array,
             return (jnp.maximum(bt[ss[i], sb[i]], 0), 0, 0, 0)
 
         def _pos_map(i, bt, ss, sb, cu):
-            return (jnp.maximum(bt[ss[i], sb[i]], 0), 0)
+            return (jnp.maximum(bt[ss[i], sb[i]], 0), 0, 0)
 
         def _scale_map(i, bt, ss, sb, cu):
             return (jnp.maximum(bt[ss[i], sb[i]], 0), 0, 0)
@@ -558,11 +565,11 @@ def ragged_paged_verify_attn_pallas(q: jax.Array, k: jax.Array,
             pl.BlockSpec((1, KVH, GT, hd), _q_map),
             pl.BlockSpec((1, bs, KVH, hd), _kv_map),
             pl.BlockSpec((1, bs, KVH, hd), _kv_map),
-            pl.BlockSpec((1, GT), _qp_map),
-            pl.BlockSpec((1, bs), _pos_map),
+            pl.BlockSpec((1, GT, 1), _qp_map),
+            pl.BlockSpec((1, 1, bs), _pos_map),
         ]
         args = [block_tables, ss, sb, cu_blocks.astype(jnp.int32),
-                qf, k, v, qpf, pos]
+                qf, k, v, qpf, pos3]
         if quant:
             in_specs += [pl.BlockSpec((1, bs, KVH), _scale_map),
                          pl.BlockSpec((1, bs, KVH), _scale_map)]
@@ -575,11 +582,7 @@ def ragged_paged_verify_attn_pallas(q: jax.Array, k: jax.Array,
             grid=(total,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, KVH, GT, hd), _q_map),
-            scratch_shapes=[
-                pltpu.VMEM((KVH, GT, hd), jnp.float32),
-                pltpu.VMEM((KVH, GT), jnp.float32),
-                pltpu.VMEM((KVH, GT), jnp.float32),
-            ],
+            scratch_shapes=_acc_scratch(KVH, GT, hd),
         )
     o = pl.pallas_call(
         kern,

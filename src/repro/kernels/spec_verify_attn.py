@@ -13,6 +13,16 @@ masked until overwritten.  Cache tiles whose positions are all outside the
 (q - window, q] visibility range are *skipped* (@pl.when) — on a 512k-row
 cache with an 8k window that's a 64x reduction in touched tiles, the
 structural equivalent of flash-decode's early exit.
+
+TPU block layout: a block's last two dimensions must be multiples of
+(8, 128) or span the whole array axis, and a ``block_k`` tile is only a
+sublane multiple.  So query positions travel as a ``[N, Tq, 1]`` column,
+key positions as ``[N, nk, bk]`` (the whole row of one batch entry stays
+resident across its k sweep; step ``j`` reads row ``j``, a ``[1, bk]``
+lane vector), and int8 scales as ``[N, L, 1]`` columns that scale the
+``[bk, hd]`` tile row-wise.  The score tile ``[Tq, bk]`` is then masked by a
+plain column-vs-row broadcast.  The resident key positions cost ``4 * L``
+bytes of VMEM per pipeline buffer (128 KiB at L = 32k).
 """
 from __future__ import annotations
 
@@ -38,8 +48,8 @@ def _verify_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    qp = qp_ref[0]                                       # [Tq]
-    kp = kp_ref[0]                                       # [bk]
+    qp = qp_ref[0]                                       # [Tq, 1]
+    kp = kp_ref[0, pl.ds(j, 1), :]                       # [1, bk]
 
     # tile-level visibility: any cache row in this tile attendable by any query?
     q_hi = qp.max()
@@ -58,29 +68,29 @@ def _verify_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref,
         if ks_ref is not None:
             # int8 cache tiles: HBM moved them at 1 B/elem; dequantize in
             # VMEM with the per-row scales (the beyond-paper kv_quant path)
-            k = k * ks_ref[0].astype(jnp.float32)[:, None]
-            v = v * vs_ref[0].astype(jnp.float32)[:, None]
+            k = k * ks_ref[0].astype(jnp.float32)        # [bk, 1] column
+            v = v * vs_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-        ok = (kp[None, :] >= 0) & (kp[None, :] <= qp[:, None])
+        ok = (kp >= 0) & (kp <= qp)                      # [Tq, bk]
         if window is not None:
-            ok &= kp[None, :] > qp[:, None] - window
+            ok &= kp > qp - window
         if prefix_len:
-            ok |= (kp[None, :] >= 0) & (kp[None, :] < prefix_len)
+            ok |= (kp >= 0) & (kp < prefix_len)
         s = jnp.where(ok, s, -jnp.inf)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
+        m_prev = m_ref[...]                              # [Tq, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
-        p = jnp.where(ok, jnp.exp(s - m_safe[:, None]), 0.0)
+        p = jnp.where(ok, jnp.exp(s - m_safe), 0.0)
         corr = jnp.where(jnp.isneginf(m_prev), 0.0, jnp.exp(m_prev - m_safe))
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())))
         m_ref[...] = m_new
 
     @pl.when(j == nk - 1)
     def _finish():
         o_ref[0] = (acc_ref[...]
-                    / jnp.maximum(l_ref[...], 1e-30)[:, None]).astype(o_ref.dtype)
+                    / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def choose_block_k(L: int, block_k: int):
@@ -156,16 +166,16 @@ def spec_verify_attn_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         pl.BlockSpec((1, Tq, hd), lambda b, j: (b, 0, 0)),
         pl.BlockSpec((1, bk, hd), lambda b, j: (b, j, 0)),
         pl.BlockSpec((1, bk, hd), lambda b, j: (b, j, 0)),
-        pl.BlockSpec((1, Tq), lambda b, j: (b, 0)),
-        pl.BlockSpec((1, bk), lambda b, j: (b, j)),
+        pl.BlockSpec((1, Tq, 1), lambda b, j: (b, 0, 0)),
+        pl.BlockSpec((1, nk, bk), lambda b, j: (b, 0, 0)),
     ]
-    args = [q, k, v, q_pos, k_pos]
+    args = [q, k, v, q_pos[:, :, None], k_pos.reshape(B, nk, bk)]
     kern = functools.partial(_verify_kernel, scale=scale, window=window,
                              prefix_len=prefix_len, nk=nk)
     if quant:
-        in_specs += [pl.BlockSpec((1, bk), lambda b, j: (b, j)),
-                     pl.BlockSpec((1, bk), lambda b, j: (b, j))]
-        args += [k_scale, v_scale]
+        in_specs += [pl.BlockSpec((1, bk, 1), lambda b, j: (b, j, 0)),
+                     pl.BlockSpec((1, bk, 1), lambda b, j: (b, j, 0))]
+        args += [k_scale[:, :, None], v_scale[:, :, None]]
 
         def kern(q_ref, k_ref, v_ref, qp_ref, kp_ref, ks_ref, vs_ref, o_ref,
                  acc_ref, m_ref, l_ref):
@@ -181,8 +191,8 @@ def spec_verify_attn_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B, Tq, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((Tq, hd), jnp.float32),
-            pltpu.VMEM((Tq,), jnp.float32),
-            pltpu.VMEM((Tq,), jnp.float32),
+            pltpu.VMEM((Tq, 1), jnp.float32),
+            pltpu.VMEM((Tq, 1), jnp.float32),
         ],
         interpret=interpret,
     )(*args)

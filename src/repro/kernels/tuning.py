@@ -27,6 +27,10 @@ owns the three host/trace-boundary pieces around it:
   falls back to :data:`DEFAULT_CONFIG` when the file or cell is missing,
   and otherwise picks the nearest recorded cell by log-distance — so an
   unmeasured shape inherits the config of its closest measured neighbour.
+  A table applies only on the device kind it was timed on (``meta``
+  ``device_kind`` against ``jax.devices()[0].device_kind``): winners timed
+  in interpret mode on the CPU say nothing about a TPU, so there — and for
+  a table that records no device kind — the lookup returns the default.
   The lookup runs at *trace* time (shapes are static), never per step.
 """
 from __future__ import annotations
@@ -37,6 +41,7 @@ import math
 import os
 from typing import Dict, Optional, Tuple
 
+import jax
 import numpy as np
 
 # results/BENCH_kernels.json relative to the repo root (three dirs up)
@@ -132,6 +137,8 @@ def clear_config_cache() -> None:
 
 
 def _load(path: str) -> Dict[str, RaggedConfig]:
+    """The autotune table at ``path`` if it was timed on this process's
+    device kind, else empty."""
     global _cache, _cache_path
     if _cache is not None and _cache_path == path:
         return _cache
@@ -139,8 +146,10 @@ def _load(path: str) -> Dict[str, RaggedConfig]:
     try:
         with open(path, encoding="utf-8") as f:
             data = json.load(f)
-        for key, rec in (data.get("autotune") or {}).items():
-            table[key] = RaggedConfig.from_json(rec.get("config", rec))
+        kind = (data.get("meta") or {}).get("device_kind")
+        if kind == jax.devices()[0].device_kind:
+            for key, rec in (data.get("autotune") or {}).items():
+                table[key] = RaggedConfig.from_json(rec.get("config", rec))
     except (OSError, ValueError):
         table = {}
     _cache, _cache_path = table, path
@@ -161,7 +170,8 @@ def lookup_config(batch: int, t: int, max_blocks: int,
 
     Exact cell if measured; else the nearest measured cell by summed
     log2-distance over the three dims (shapes scale geometrically, so log
-    distance matches how configs generalize); else the safe default.
+    distance matches how configs generalize); else the safe default — also
+    whenever the table was timed on another device kind.
     """
     table = _load(path or RESULTS_PATH)
     if not table:

@@ -104,12 +104,27 @@ class PrefixCache:
         return [n.block for n in self._walk(tokens)]
 
     def reclaimable_ids(self) -> List[int]:
-        """Ids of indexed blocks held by the cache alone (refcount 1)."""
-        return [b for b in self._blocks if self.pool.refcount(b) == 1]
+        """Ids of indexed blocks :meth:`reclaim` could free right now: held
+        by the cache alone (refcount 1) with every descendant cache-only
+        too.  Eviction is leaf-first, so a cache-only block above one a
+        slot still holds (a slot that copied-on-write the shared parent
+        but kept the child) cannot be freed and does not count."""
+        ids: List[int] = []
+
+        def cache_only(node: _Node) -> bool:
+            below = [cache_only(c) for c in node.children.values()]
+            ok = all(below) and self.pool.refcount(node.block) == 1
+            if ok:
+                ids.append(node.block)
+            return ok
+
+        for child in self._root.children.values():
+            cache_only(child)
+        return ids
 
     def reclaimable(self) -> int:
         """How many indexed blocks eviction could free right now."""
-        return sum(self.pool.refcount(b) == 1 for b in self._blocks)
+        return len(self.reclaimable_ids())
 
     # ------------------------------------------------------------------
     # admission protocol

@@ -123,6 +123,21 @@ def test_transfer_free_catches_callback():
     jaxprs = {(e.name, e.key): e.fn.trace(*e.arg_specs).jaxpr}
     findings = transfer_free.check([e], jaxprs)
     assert len(findings) == 1 and findings[0].rule == "transfer-free"
+    # jax.debug.print traces to its own `debug_print` primitive
+    assert "`debug_print`" in findings[0].message
+
+
+def test_transfer_free_catches_pure_callback():
+    def fn(x):
+        return jax.pure_callback(lambda a: a, jax.ShapeDtypeStruct(
+            x.shape, x.dtype), x) + 1
+
+    e = _entry(name="step", key=(2, 2))
+    e.fn = jax.jit(fn)
+    e.arg_specs = (jax.ShapeDtypeStruct((4,), jnp.float32),)
+    jaxprs = {(e.name, e.key): e.fn.trace(*e.arg_specs).jaxpr}
+    findings = transfer_free.check([e], jaxprs)
+    assert len(findings) == 1 and findings[0].rule == "transfer-free"
     assert "callback" in findings[0].message
 
 

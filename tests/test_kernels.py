@@ -8,7 +8,6 @@ import pytest
 
 from repro.kernels import ops
 from repro.kernels import ref as R
-from repro.kernels.flash_attn import flash_attn_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.spec_verify_attn import spec_verify_attn_pallas
 from repro.kernels.ssd_chunk import ssd_chunk_pallas
@@ -37,39 +36,37 @@ def test_rmsnorm_matches_ref(shape, dtype):
 
 
 # ---------------------------------------------------------------------------
-# flash attention kernel (folded single-kv-head contract)
+# spec-verify kernel
 
 
-@pytest.mark.parametrize("Tq,Tk,hd,bq,bk", [
-    (16, 32, 32, 8, 8), (32, 32, 64, 16, 16), (8, 64, 128, 8, 32)])
-def test_flash_kernel_causal(Tq, Tk, hd, bq, bk):
+@pytest.mark.parametrize("Tq,Tk,hd,bk", [
+    (16, 32, 32, 8), (32, 32, 64, 16), (8, 64, 128, 32)])
+def test_verify_kernel_causal_chunk(Tq, Tk, hd, bk):
+    """Prefill-chunk-sized q blocks (a chunk's prefix extension rides the
+    same kernel): causal over the whole key range."""
     B = 2
     q, k, v = _rand((B, Tq, hd), k=1), _rand((B, Tk, hd), k=2), _rand((B, Tk, hd), k=3)
     qp = jnp.broadcast_to(jnp.arange(Tq, dtype=jnp.int32) + Tk - Tq, (B, Tq))
     kp = jnp.broadcast_to(jnp.arange(Tk, dtype=jnp.int32), (B, Tk))
-    got = flash_attn_pallas(q, k, v, qp, kp, block_q=bq, block_k=bk,
-                            interpret=True)
+    got = spec_verify_attn_pallas(q, k, v, qp, kp, block_k=bk, interpret=True)
     want = R.flash_attn_ref(q, k, v, qp, kp)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("window,prefix", [(None, 0), (8, 0), (None, 6), (8, 6)])
-def test_flash_kernel_masking_modes(window, prefix):
+def test_verify_kernel_masking_modes(window, prefix):
     B, Tq, Tk, hd = 2, 16, 48, 32
     q, k, v = _rand((B, Tq, hd), k=4), _rand((B, Tk, hd), k=5), _rand((B, Tk, hd), k=6)
     qp = jnp.broadcast_to(jnp.arange(Tq, dtype=jnp.int32) + 20, (B, Tq))
     kp = jnp.where(jnp.arange(Tk) < 40, jnp.arange(Tk, dtype=jnp.int32), -1)
     kp = jnp.broadcast_to(kp, (B, Tk))
-    got = flash_attn_pallas(q, k, v, qp, kp, window=window, prefix_len=prefix,
-                            block_q=8, block_k=16, interpret=True)
+    got = spec_verify_attn_pallas(q, k, v, qp, kp, window=window,
+                                  prefix_len=prefix, block_k=16,
+                                  interpret=True)
     want = R.flash_attn_ref(q, k, v, qp, kp, window=window, prefix_len=prefix)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
-
-
-# ---------------------------------------------------------------------------
-# spec-verify kernel
 
 
 @pytest.mark.parametrize("Tq", [1, 2, 5, 8])
@@ -117,11 +114,6 @@ def test_ops_gqa_folding(H, KVH):
     want = ops.spec_verify_attn(q, k, v, qp, kp, use_pallas=False)
     got = ops.spec_verify_attn(q, k, v, qp, kp, use_pallas=True, block_k=16)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-    wantf = ops.flash_attn(q, k, v, qp, kp, use_pallas=False)
-    gotf = ops.flash_attn(q, k, v, qp, kp, use_pallas=True,
-                          block_q=8, block_k=16)
-    np.testing.assert_allclose(np.asarray(gotf), np.asarray(wantf),
                                rtol=2e-5, atol=2e-5)
 
 
@@ -178,7 +170,7 @@ def test_train_tri_and_ref_attention_agree():
     pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     a = cm.flash_attention_tri(q, k, v, pos, pos, block_q=8, block_k=8)
     b_ = cm.flash_attention_train(q, k, v, pos, pos, block_q=8)
-    c_ = ops.flash_attn(q, k, v, pos, pos, use_pallas=False)
+    c_ = R.gqa_masked_ref(q, k, v, pos, pos, None, 0, 1.0 / np.sqrt(hd))
     np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(a), np.asarray(c_), rtol=2e-5, atol=2e-5)
 

@@ -128,6 +128,25 @@ def test_radix_first_writer_wins_and_rejects_double_index():
         pc.insert(tokens, kv.table(0)[:1])   # fewer blocks than token blocks
 
 
+def test_reclaimable_counts_only_what_reclaim_can_free():
+    """A slot that copied-on-write a shared parent block but still holds
+    its child leaves the parent cache-only yet unevictable (eviction is
+    leaf-first): admission feasibility must not count it."""
+    kv, pc = _kv(num_blocks=4, capacity=2, max_blocks=3)
+    tokens = np.arange(0, 2 * BS, dtype=np.int32)
+    kv.prefill(0, 2 * BS)
+    pc.insert(tokens, kv.table(0))
+    kv.release(0)
+    kv.attach(1, pc.lock(tokens), 2 * BS)
+    pc.unlock(kv.table(1))
+    parent, child = pc.match(tokens)
+    kv.cow_for_range(1, 0, 1)                # slot 1 now owns a copy of 0
+    assert kv.pool.refcount(parent) == 1 and kv.pool.refcount(child) == 2
+    assert pc.reclaimable() == 0 == len(pc.reclaim(10))
+    # what is counted is exactly what an allocation can obtain
+    assert kv.available_blocks == kv.pool.free_count == 1
+
+
 def test_lock_pins_against_reclaim():
     kv, pc = _kv()
     t_a = np.arange(0, 2 * BS, dtype=np.int32)

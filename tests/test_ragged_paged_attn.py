@@ -194,6 +194,8 @@ def test_lookup_config_exact_nearest_default(tmp_path):
     clear_config_cache()
     assert lookup_config(4, 4, 8, path=path) == DEFAULT_CONFIG  # no file
     table = {
+        # a table applies only on the device kind it was timed on
+        "meta": {"device_kind": jax.devices()[0].device_kind},
         "autotune": {
             cell_key(4, 4, 8): {"config": {"num_buffers": 2,
                                            "vmem_limit_bytes": None}},
@@ -209,6 +211,29 @@ def test_lookup_config_exact_nearest_default(tmp_path):
     assert lookup_config(7, 4, 14, path=path) == RaggedConfig(
         num_buffers=4, vmem_limit_bytes=32 << 20)
     clear_config_cache()
+
+
+@pytest.mark.parametrize("meta", [{"device_kind": "TPU v5 lite"}, {}],
+                         ids=["other-kind", "no-kind"])
+def test_lookup_config_ignores_table_of_other_device(tmp_path, meta):
+    """Winners timed on one device kind (or on an unrecorded one) never
+    steer launches on another: the lookup falls back to the default."""
+    path = str(tmp_path / "bench.json")
+    with open(path, "w") as f:
+        json.dump({"meta": meta, "autotune": {cell_key(4, 4, 8): {
+            "config": {"num_buffers": 2, "vmem_limit_bytes": None}}}}, f)
+    clear_config_cache()
+    assert lookup_config(4, 4, 8, path=path) == DEFAULT_CONFIG
+    clear_config_cache()
+
+
+def test_committed_autotune_table_is_tagged_with_its_device():
+    """The committed table was timed in interpret mode on the CPU and says
+    so, so a TPU process never picks up its launch knobs."""
+    from repro.kernels.tuning import RESULTS_PATH
+    with open(RESULTS_PATH, encoding="utf-8") as f:
+        meta = json.load(f)["meta"]
+    assert meta["device_kind"] == "cpu" and meta["execution"] == "interpret"
 
 
 # ---------------------------------------------------------------------------

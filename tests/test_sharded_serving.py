@@ -117,3 +117,55 @@ def test_sharded_serve_parity_two_devices():
     assert out["contiguous"]["iters"] > 0
     assert out["paged"]["preempts"] > 0, out
     assert out["chunked"]["chunks"] > 0, out
+
+
+KERNEL_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=2 "
+                           + os.environ.get("XLA_FLAGS", ""))
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.kernels import ops
+from repro.launch.mesh import make_serving_mesh
+
+mesh = make_serving_mesh(2)
+rng = np.random.default_rng(0)
+for B in (4, 1):                 # a batch that splits, and B=1 (replicated)
+    T, H, KVH, hd, L = 3, 4, 2, 32, 40
+    q = rng.standard_normal((B, T, H, hd)).astype(np.float32)
+    k = rng.standard_normal((B, L, KVH, hd)).astype(np.float32)
+    v = rng.standard_normal((B, L, KVH, hd)).astype(np.float32)
+    lens = rng.integers(T, L, B)
+    qp = (lens - T)[:, None] + np.arange(T)[None]
+    kp = np.where(np.arange(L)[None] < lens[:, None], np.arange(L)[None], -1)
+    args = [jnp.asarray(a) for a in (q, k, v, qp.astype(np.int32),
+                                     kp.astype(np.int32))]
+    sh = NamedSharding(mesh, P("data") if B % 2 == 0 else P())
+
+    def f(*a):
+        with ops.partition_mesh(mesh):
+            return ops.spec_verify_attn(*a, block_k=8, use_pallas=True)
+
+    got = jax.jit(f, in_shardings=(sh,) * 5, out_shardings=sh)(*args)
+    want = ops.spec_verify_attn(*args, use_pallas=False)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    text = jax.jit(f, in_shardings=(sh,) * 5).lower(*args).as_text()
+    manual = [l for l in text.splitlines() if "sdy.manual_computation" in l]
+    assert len(manual) == 1, text[:2000]
+    # B=4 splits over "data"; B=1 runs whole on each device
+    assert ('[{"data"}' in manual[0]) == (B % 2 == 0), manual[0][:300]
+print("ok")
+"""
+
+
+def test_verify_kernel_runs_per_device_under_a_mesh():
+    """Under a 2-device mesh the verify kernel runs inside shard_map (XLA
+    cannot partition a Pallas TPU kernel): per-device batch slices, or the
+    whole batch on each device for B=1, both equal to the reference."""
+    env = dict(os.environ, PYTHONPATH=SRC)
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run([sys.executable, "-c", KERNEL_SCRIPT], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    assert proc.stdout.strip().splitlines()[-1] == "ok"

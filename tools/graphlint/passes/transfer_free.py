@@ -19,8 +19,9 @@ PASS = "transfer-free"
 
 # Primitive names that imply a host round-trip.  Substring match on
 # "callback" covers pure_callback / io_callback / debug_callback and
-# whatever jax renames them to next.
-_BLOCKED_EXACT = {"infeed", "outfeed"}
+# whatever jax renames them to next; ``jax.debug.print`` traces to its own
+# ``debug_print`` primitive.
+_BLOCKED_EXACT = {"infeed", "outfeed", "debug_print"}
 _BLOCKED_SUBSTR = ("callback",)
 
 
