@@ -142,8 +142,8 @@ def bench_sim_paged_chunked() -> Dict:
     res = sched.run(reqs)
     res.trace = sched.trace
     out = _metrics(res, tel)
-    out["n_preemptions"] = int(tel.counters.get("preempt", 0))
-    out["n_chunk_feeds"] = int(tel.counters.get("chunk_continue", 0))
+    out["n_preemptions"] = int(tel.counters.get("sched.preempt", 0))
+    out["n_chunk_feeds"] = int(tel.counters.get("sched.chunk_continue", 0))
     return out
 
 

@@ -55,16 +55,6 @@ TOKEN_BUDGET = 256                # PrefillBudgetAdmit: prefill tokens/iter
 CHUNK = 128                       # and its chunk width
 KERNEL_TOL = 2e-2                 # bf16 kernel vs float32 reference
 
-# JAX's compile-time events.  Trace spans nest (an inner jit's trace runs
-# inside its caller's), so only the backend compile — which also covers a
-# persistent-cache read when the cache hits — is a clean total, and it is
-# the part the persistent cache saves.
-_COMPILE_EVENTS = {"/jax/core/compile/jaxpr_trace_duration": "trace",
-                   "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
-                   "/jax/core/compile/backend_compile_duration": "backend"}
-_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "cache_hits",
-                 "/jax/compilation_cache/cache_misses": "cache_misses"}
-_clock: dict = {}                 # running totals once the listeners exist
 
 
 class CheckFailed(RuntimeError):
@@ -97,36 +87,35 @@ def _use_compile_cache(jax) -> str:
     return CACHE_DIR
 
 
-def compile_clock() -> dict:
-    """Totals since the first call: seconds spent tracing, lowering and
-    backend-compiling, and persistent-cache hits and misses."""
-    if not _clock:
-        import jax
-        _clock.update(dict.fromkeys([*_COMPILE_EVENTS.values(),
-                                     *_CACHE_EVENTS.values()], 0))
-
-        def on_duration(event, duration, **_):
-            if event in _COMPILE_EVENTS:
-                _clock[_COMPILE_EVENTS[event]] += duration
-
-        def on_event(event, **_):
-            if event in _CACHE_EVENTS:
-                _clock[_CACHE_EVENTS[event]] += 1
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
-        jax.monitoring.register_event_listener(on_event)
-    return dict(_clock)
-
-
-def compile_since(start: dict) -> dict:
-    """:func:`compile_clock` totals accrued since ``start``."""
-    return {k: v - start[k] for k, v in compile_clock().items()}
+def compile_since(t0: float) -> dict:
+    """What the program's compile log (``serving/telemetry.py``) holds after
+    ``time.perf_counter()`` time ``t0``: seconds spent tracing, lowering and
+    backend-compiling, programs compiled, and how many of those were read
+    from the persistent cache.  Trace spans nest (an inner jit's trace runs
+    inside its caller's), so only the backend compile — which also covers a
+    persistent-cache read — is a clean total, and it is the part the
+    persistent cache saves."""
+    from repro.serving.telemetry import compile_log
+    out = {"trace": 0.0, "lower": 0.0, "backend": 0.0, "programs": 0,
+           "cache_reads": 0}
+    for t_end, secs, event, _ in list(compile_log()):
+        if t_end < t0:
+            continue
+        if event == "compile":
+            out["backend"] += secs
+            out["programs"] += 1
+        elif event == "cache_read":
+            out["cache_reads"] += 1
+        else:
+            out[event] += secs
+    return out
 
 
 def describe_compile(c: dict) -> str:
     """One log fragment for a :func:`compile_since` result."""
     return (f"compile {c['backend']:.3f} s (trace {c['trace']:.3f} s, "
-            f"lower {c['lower']:.3f} s; persistent cache {c['cache_hits']} "
-            f"hits, {c['cache_misses']} misses)")
+            f"lower {c['lower']:.3f} s; {c['programs']} programs, "
+            f"{c['cache_reads']} read from the persistent cache)")
 
 
 # ---------------------------------------------------------------------------
@@ -280,7 +269,7 @@ def serve_once(eng, tparams, dparams, requests, lut, *, mesh=None,
     reqs = [dataclasses.replace(r, tokens=np.array(r.tokens))
             for r in requests]
     ctrl = AdaptiveController(lut=SpeculationLUT(dict(lut)))
-    c0, t0 = compile_clock(), time.perf_counter()
+    t0 = time.perf_counter()
     be = ContinuousEngineBackend(eng, tparams, dparams, capacity=capacity,
                                  cache_len=cache_len,
                                  warm_s=sorted(set(lut.values())),
@@ -292,7 +281,7 @@ def serve_once(eng, tparams, dparams, requests, lut, *, mesh=None,
     wall = time.perf_counter() - t0
     return {"outputs": {r: np.asarray(o) for r, o in be.outputs.items()},
             "backend": be, "wall_s": wall,
-            "compile": compile_since(c0),
+            "compile": compile_since(t0),
             "chunked": sum(len(t.chunked) for t in res.trace),
             "steps": len(res.trace)}
 
@@ -429,7 +418,8 @@ def main(argv=None) -> int:
     import jax
     import jax.numpy as jnp
     cache = _use_compile_cache(jax)
-    compile_clock()
+    from repro.serving.telemetry import compile_log
+    compile_log()                     # listen from the start
     devs = jax.devices()
     dev = devs[0]
     if dev.platform != "tpu":
@@ -459,12 +449,12 @@ def main(argv=None) -> int:
               f"{kc['served_config']}; ragged vs dense max diff "
               f"{kc['ragged_vs_dense_max_diff']}", flush=True)
         res = serving_phase(tcfg, dcfg, seed=args.seed, dtype=jnp.bfloat16)
-        c0 = compile_clock()
+        t0 = time.perf_counter()
         text = verify_step_text(res["engine"], max(LUT.values()))
         has_kernel = "tpu_custom_call" in text
         print(f"verify step s={max(LUT.values())}: tpu_custom_call in "
               f"compiled program: {has_kernel} (inspection "
-              f"{describe_compile(compile_since(c0))})", flush=True)
+              f"{describe_compile(compile_since(t0))})", flush=True)
         _check(has_kernel, "the compiled verify step holds no Pallas TPU "
                            "kernel (tpu_custom_call)")
         count = 1

@@ -100,10 +100,10 @@ Params = Any
 # writes past the buffer, so SpecDecodeEngine.step validates s <= S_MAX.
 S_MAX = 8
 
-# shared no-op context for the `engine.annotate` guards below: when device
-# annotation is off, each jit dispatch enters this (reentrant, stateless)
-# instead of constructing a jax.profiler.TraceAnnotation — the off path
-# does no string formatting and allocates nothing
+# shared no-op context of SpecDecodeEngine.scope: with annotation off and no
+# hub, every span site enters this (reentrant, stateless) instead of
+# constructing a span — the off path does no string formatting and
+# allocates nothing
 _NULLCTX = contextlib.nullcontext()
 
 
@@ -292,13 +292,16 @@ class SpecDecodeEngine:
         # buffers.  graph-lint's donation pass is the standing proof that
         # the default stays on and actually aliases in the lowered HLO.
         self.donate = donate
-        # opt-in device-side phase tracing (serving/telemetry.py): when
-        # True, every jit dispatch runs under a jax.profiler.TraceAnnotation
-        # scope so a profiler trace attributes device time per serving
-        # phase.  TraceAnnotation is a no-op outside an active trace; with
-        # the flag False the dispatch sites enter a shared nullcontext and
-        # never even format the annotation name.
+        # the span switch (serving/telemetry.py): when True, every span site
+        # of the engine, the serving backend and the scheduler — host prep,
+        # syncs, and each jit dispatch — runs under a ``repro/<phase>``
+        # jax.profiler.TraceAnnotation, so a profiler trace attributes host
+        # and device time per phase.  ``telemetry`` (a hub) additionally
+        # records every span with perf_counter stamps.  With the flag False
+        # and no hub, span sites enter a shared nullcontext and never even
+        # format the span name.
         self.annotate: bool = False
+        self.telemetry = None
         # draft models are text-only: for VLM targets their positions run
         # without the modality prefix offset
         self.prefix_offset = target_cfg.prefix_len if target_cfg.family == "vlm" else 0
@@ -373,6 +376,24 @@ class SpecDecodeEngine:
         self._evict_fn = None
         self.jit_registry.clear()
 
+    def scope(self, phase: str, *fmt):
+        """The span of one phase (``telemetry.span``), named
+        ``phase.format(*fmt)``: formatted and built only when the span
+        switch is on or a hub is attached."""
+        if not self.annotate and self.telemetry is None:
+            return _NULLCTX
+        from repro.serving.telemetry import span
+        return span(phase.format(*fmt) if fmt else phase, self.telemetry,
+                    self.annotate)
+
+    @property
+    def compile_log(self):
+        """The process-wide compile log (``telemetry.compile_log``); each
+        entry's ``fun_name`` names the engine jit by registry entry and
+        key."""
+        from repro.serving.telemetry import compile_log
+        return compile_log()
+
     def _register_jit(self, name: str, key: Tuple, fn, *, hot: bool,
                       kv_args: Tuple[int, ...] = (),
                       in_shardings=None, out_shardings=None,
@@ -388,6 +409,8 @@ class SpecDecodeEngine:
         per-entry trace counter and arg/out spec capture cost nothing on
         the cached dispatch path.
         """
+        from repro.serving.telemetry import compile_log
+        compile_log()                  # listen before the first compile
         donate = tuple(kv_args) if self.donate else ()
         code = fn.__code__
         entry = JitEntry(
@@ -409,6 +432,9 @@ class SpecDecodeEngine:
                 out = fn(*args, **kwargs)
             entry.out_specs = jax.tree.map(_trace_spec, out)
             return out
+        # the compile log's fun_name: registry entry and key
+        counted.__name__ = (f"{name}__{'_'.join(map(str, key))}" if key
+                            else name)
 
         kw: Dict[str, Any] = {}
         if in_shardings is not None:
@@ -461,8 +487,7 @@ class SpecDecodeEngine:
         key = (B, P, cache_len)
         if key not in self._prefill_fns:
             self._prefill_fns[key] = self._build_prefill(B, P, cache_len)
-        with (jax.profiler.TraceAnnotation(f"repro/prefill[B={B},P={P}]")
-              if self.annotate else _NULLCTX):
+        with self.scope("prefill[B={},P={}]", B, P):
             tcache, dcache, seq_lens, last2 = self._prefill_fns[key](
                 tparams, dparams, jnp.asarray(tokens),
                 jnp.asarray(prompt_lens), target_extras or {})
@@ -733,25 +758,24 @@ class SpecDecodeEngine:
                     state.out, state.n_generated, state.done)
             one = (single.tcache, single.dcache, single.seq_lens, single.last2,
                    single.out, single.n_generated, single.done)
-            with (jax.profiler.TraceAnnotation("repro/inject")
-                  if self.annotate else _NULLCTX):
+            with self.scope("inject"):
                 return DecodeState(*self._inject_fn(full, one,
                                                     jnp.int32(slot)))
         pk = state.paged
-        scat_tbl = np.full((pk.max_blocks,), pk.num_blocks, np.int32)
-        bt_row = np.full((pk.max_blocks,), -1, np.int32)
-        if not warm:
-            pk.prefill(slot, prompt_len)
-            ids = pk.table(slot)
-            scat_tbl[:len(ids)] = ids
-            bt_row[:len(ids)] = ids
-            # the allocation may have reclaimed cache blocks: wipe their
-            # stale pos rows before the inject can hand them a new owner
-            state = self._drain_evicted(state)
+        with self.scope("prefill.prep"):
+            scat_tbl = np.full((pk.max_blocks,), pk.num_blocks, np.int32)
+            bt_row = np.full((pk.max_blocks,), -1, np.int32)
+            if not warm:
+                pk.prefill(slot, prompt_len)
+                ids = pk.table(slot)
+                scat_tbl[:len(ids)] = ids
+                bt_row[:len(ids)] = ids
+                # the allocation may have reclaimed cache blocks: wipe their
+                # stale pos rows before the inject can hand them a new owner
+                state = self._drain_evicted(state)
         if self._inject_paged_fn is None:
             self._inject_paged_fn = self._build_inject_paged()
-        with (jax.profiler.TraceAnnotation("repro/inject")
-              if self.annotate else _NULLCTX):
+        with self.scope("inject"):
             tcache = self._inject_paged_fn(state.tcache, single.tcache,
                                            jnp.int32(slot),
                                            jnp.asarray(scat_tbl),
@@ -779,9 +803,10 @@ class SpecDecodeEngine:
         sh = self._shardings
         if state.paged is not None:
             pk = state.paged
-            freed = pk.release(slot)
-            pad = np.full((pk.max_blocks,), pk.num_blocks, np.int32)
-            pad[:len(freed)] = freed
+            with self.scope("retire.prep"):
+                freed = pk.release(slot)
+                pad = np.full((pk.max_blocks,), pk.num_blocks, np.int32)
+                pad[:len(freed)] = freed
             if self._retire_paged_fn is None:
                 def fn(done, pos, bt, slot, freed):
                     return (done.at[slot].set(True),
@@ -797,8 +822,7 @@ class SpecDecodeEngine:
                                       sh.tcache["bt"], sh.rep, sh.rep),
                         out_shardings=(sh.done, sh.tcache["pos"],
                                        sh.tcache["bt"]))
-            with (jax.profiler.TraceAnnotation("repro/retire")
-                  if self.annotate else _NULLCTX):
+            with self.scope("retire"):
                 done, pos, bt = self._retire_paged_fn(
                     state.done, state.tcache["pos"], state.tcache["bt"],
                     jnp.int32(slot), jnp.asarray(pad))
@@ -812,8 +836,7 @@ class SpecDecodeEngine:
                 self._register_jit("retire", (), fn, hot=True, kv_args=(0,),
                                    in_shardings=(sh.done, sh.rep),
                                    out_shardings=sh.done))
-        with (jax.profiler.TraceAnnotation("repro/retire")
-              if self.annotate else _NULLCTX):
+        with self.scope("retire"):
             done = self._retire_fn(state.done, jnp.int32(slot))
         return dataclasses.replace(state, done=done)
 
@@ -917,8 +940,7 @@ class SpecDecodeEngine:
         pad[:len(ids)] = ids
         if self._evict_fn is None:
             self._evict_fn = self._build_evict_clear()
-        with (jax.profiler.TraceAnnotation("repro/evict_clear")
-              if self.annotate else _NULLCTX):
+        with self.scope("evict_clear"):
             pos = self._evict_fn(state.tcache["pos"], jnp.asarray(pad))
         return dataclasses.replace(state, tcache=dict(state.tcache, pos=pos))
 
@@ -958,16 +980,14 @@ class SpecDecodeEngine:
             dlim = min(n_prefix, total_len - 2)
             if (P, L) not in self._attach_fns:
                 self._attach_fns[(P, L)] = self._build_draft_prefill(P, L)
-            with (jax.profiler.TraceAnnotation(f"repro/draft_prefill[P={P}]")
-                  if self.annotate else _NULLCTX):
+            with self.scope("draft_prefill[P={}]", P):
                 d_single = self._attach_fns[(P, L)](
                     dparams, jnp.asarray(tokens),
                     jnp.full((1,), dlim, jnp.int32))
         if has_draft not in self._attach_park_fns:
             self._attach_park_fns[has_draft] = \
                 self._build_attach_park(has_draft)
-        with (jax.profiler.TraceAnnotation("repro/attach_park")
-              if self.annotate else _NULLCTX):
+        with self.scope("attach_park"):
             if has_draft:
                 dcache, seq_lens = self._attach_park_fns[True](
                     state.dcache, d_single, state.seq_lens, jnp.int32(slot),
@@ -1025,8 +1045,7 @@ class SpecDecodeEngine:
                 src[i], dst[i] = s_, d_
             if self._block_copy_fn is None:
                 self._block_copy_fn = self._build_block_copy()
-            with (jax.profiler.TraceAnnotation("repro/block_copy")
-                  if self.annotate else _NULLCTX):
+            with self.scope("block_copy"):
                 tcache = self._block_copy_fn(state.tcache, jnp.asarray(src),
                                              jnp.asarray(dst))
             state = dataclasses.replace(state, tcache=tcache)
@@ -1039,8 +1058,7 @@ class SpecDecodeEngine:
                  state.done, jnp.int32(slot), jnp.int32(total_len),
                  jnp.asarray(np.asarray(last2, np.int32)),
                  state.tcache["bt"], jnp.asarray(bt_row))
-        with (jax.profiler.TraceAnnotation("repro/chunk_commit")
-              if self.annotate else _NULLCTX):
+        with self.scope("chunk_commit"):
             seq_lens, l2, out, n_gen, done, bt = \
                 self._chunk_commit_fns[True](*cargs)
         return dataclasses.replace(
@@ -1315,9 +1333,10 @@ class SpecDecodeEngine:
             dpos = (state.dcache["pos"]
                     if (self.draft is not None and isinstance(state.dcache, dict)
                         and "pos" in state.dcache) else None)
-            tpos, dpos_new, seq_lens = self._chunk_begin_fns[paged](
-                state.tcache["pos"], dpos, state.seq_lens, jnp.int32(slot),
-                jnp.int32(total_len))
+            with self.scope("chunk_begin"):
+                tpos, dpos_new, seq_lens = self._chunk_begin_fns[paged](
+                    state.tcache["pos"], dpos, state.seq_lens,
+                    jnp.int32(slot), jnp.int32(total_len))
             # rebind even when warm: begin just consumed (donated) the
             # shielded copy's pos/seq_lens buffers, so the warm chunk and
             # commit dispatches below must see the outputs, not the inputs
@@ -1329,45 +1348,46 @@ class SpecDecodeEngine:
                                         dcache=dcache, seq_lens=seq_lens)
 
         # ---- host block accounting + this chunk's block table ----
-        bt_row = None
-        if paged:
-            bt_row = np.full((pk.max_blocks,), -1, np.int32)
-            if not warm:
-                if start == 0:
-                    pk.prefill(slot, n)
-                    pk.mark_pending(slot)
-                else:
-                    pk.ensure(slot, start + n)
-                    pk.commit(slot, n)
-                ids = pk.table(slot)
-                bt_row[:len(ids)] = ids
-                state = self._drain_evicted(state)
+        with self.scope("chunk.prep"):
+            bt_row = None
+            if paged:
+                bt_row = np.full((pk.max_blocks,), -1, np.int32)
+                if not warm:
+                    if start == 0:
+                        pk.prefill(slot, n)
+                        pk.mark_pending(slot)
+                    else:
+                        pk.ensure(slot, start + n)
+                        pk.commit(slot, n)
+                    ids = pk.table(slot)
+                    bt_row[:len(ids)] = ids
+                    state = self._drain_evicted(state)
+            L = (pk.logical_len if paged
+                 else int(state.tcache["pos"].shape[1]))
+            # rows-limit bucket: every attendable key lives below row
+            # start + CB (positions equal rows before the first wrap, and
+            # chunks never wrap), so the contiguous/draft forwards attend a
+            # power-of-two cover of it instead of the whole logical cache
+            R = min(max(_next_pow2(start + CB), 16), L)
+            key = (CB, paged, capacity, L, R)
+            fn = self._get_chunk_fn(key)
+            if defer:
+                if not paged or final or warm:
+                    raise ValueError(
+                        "defer=True needs a paged, non-final, non-warm chunk")
+                return state, DeferredChunk(
+                    slot=int(slot), tokens=tokens, start=int(start),
+                    total_len=int(total_len), bt_row=bt_row, key=key)
+            args = (tparams, dparams, state.tcache, state.dcache,
+                    jnp.int32(slot), jnp.asarray(tokens), jnp.int32(start),
+                    jnp.int32(feed_total), jnp.int32(feed_total - 1))
+            if paged:
+                # [0, max(live, 1)]: the slot's one-row ragged grid plan
+                cu_row = host_cu_blocks(bt_row[None, :])
+                args = args + (jnp.asarray(bt_row), jnp.asarray(cu_row))
 
         # ---- the chunk forward ----
-        L = (pk.logical_len if paged else int(state.tcache["pos"].shape[1]))
-        # rows-limit bucket: every attendable key lives below row
-        # start + CB (positions equal rows before the first wrap, and
-        # chunks never wrap), so the contiguous/draft forwards attend a
-        # power-of-two cover of it instead of the whole logical cache
-        R = min(max(_next_pow2(start + CB), 16), L)
-        key = (CB, paged, capacity, L, R)
-        fn = self._get_chunk_fn(key)
-        if defer:
-            if not paged or final or warm:
-                raise ValueError(
-                    "defer=True needs a paged, non-final, non-warm chunk")
-            return state, DeferredChunk(
-                slot=int(slot), tokens=tokens, start=int(start),
-                total_len=int(total_len), bt_row=bt_row, key=key)
-        args = (tparams, dparams, state.tcache, state.dcache,
-                jnp.int32(slot), jnp.asarray(tokens), jnp.int32(start),
-                jnp.int32(feed_total), jnp.int32(feed_total - 1))
-        if paged:
-            # [0, max(live, 1)]: the slot's one-row ragged grid plan
-            cu_row = host_cu_blocks(bt_row[None, :])
-            args = args + (jnp.asarray(bt_row), jnp.asarray(cu_row))
-        with (jax.profiler.TraceAnnotation(f"repro/chunk[CB={CB}]")
-              if self.annotate else _NULLCTX):
+        with self.scope("chunk[CB={}]", CB):
             new_t, new_d = fn(*args)
         if warm:
             # compile the commit path too, then discard everything
@@ -1388,25 +1408,27 @@ class SpecDecodeEngine:
 
         # ---- final chunk: the slot becomes a live decode row ----
         if final:
-            if paged:
-                # cover row total_len - 1 (written by the first decode step)
-                pk.ensure(slot, total_len)
-                pk.commit(slot, 1)
-                pk.clear_pending(slot)
-                ids = pk.table(slot)
-                bt_row = np.full((pk.max_blocks,), -1, np.int32)
-                bt_row[:len(ids)] = ids
-                state = self._drain_evicted(state)
-            if paged not in self._chunk_commit_fns:
-                self._chunk_commit_fns[paged] = self._build_chunk_commit(paged)
-            cargs = (state.seq_lens, state.last2, state.out,
-                     state.n_generated, state.done, jnp.int32(slot),
-                     jnp.int32(total_len),
-                     jnp.asarray(np.asarray(last2, np.int32)))
+            with self.scope("chunk.prep"):
+                if paged:
+                    # cover row total_len - 1 (written by the first decode
+                    # step)
+                    pk.ensure(slot, total_len)
+                    pk.commit(slot, 1)
+                    pk.clear_pending(slot)
+                    ids = pk.table(slot)
+                    bt_row = np.full((pk.max_blocks,), -1, np.int32)
+                    bt_row[:len(ids)] = ids
+                    state = self._drain_evicted(state)
+                if paged not in self._chunk_commit_fns:
+                    self._chunk_commit_fns[paged] = \
+                        self._build_chunk_commit(paged)
+                cargs = (state.seq_lens, state.last2, state.out,
+                         state.n_generated, state.done, jnp.int32(slot),
+                         jnp.int32(total_len),
+                         jnp.asarray(np.asarray(last2, np.int32)))
             if paged:
                 cargs = cargs + (state.tcache["bt"], jnp.asarray(bt_row))
-                with (jax.profiler.TraceAnnotation("repro/chunk_commit")
-                      if self.annotate else _NULLCTX):
+                with self.scope("chunk_commit"):
                     seq_lens, l2, out, n_gen, done, bt = \
                         self._chunk_commit_fns[paged](*cargs)
                 state = dataclasses.replace(
@@ -1414,8 +1436,7 @@ class SpecDecodeEngine:
                     n_generated=n_gen, done=done,
                     tcache=dict(state.tcache, bt=bt))
             else:
-                with (jax.profiler.TraceAnnotation("repro/chunk_commit")
-                      if self.annotate else _NULLCTX):
+                with self.scope("chunk_commit"):
                     seq_lens, l2, out, n_gen, done = \
                         self._chunk_commit_fns[paged](*cargs)
                 state = dataclasses.replace(
@@ -1441,8 +1462,7 @@ class SpecDecodeEngine:
                 jnp.int32(chunk.start), jnp.int32(feed_total),
                 jnp.int32(feed_total - 1), jnp.asarray(chunk.bt_row),
                 jnp.asarray(cu_row))
-        with (jax.profiler.TraceAnnotation(f"repro/chunk[CB={CB}]")
-              if self.annotate else _NULLCTX):
+        with self.scope("chunk[CB={}]", CB):
             new_t, new_d = fn(*args)
         return dataclasses.replace(state, tcache=new_t, dcache=new_d)
 
@@ -1471,50 +1491,54 @@ class SpecDecodeEngine:
         pk = state.paged
         if pk is None:
             raise ValueError("step_with_chunk needs a paged slot pool")
-        grew = False
-        for slot in pk.active_slots():
-            if pk.is_pending(slot):
-                continue
-            grew |= bool(pk.ensure(slot, pk.tokens(slot) + s))
-        if grew:
-            state = dataclasses.replace(
-                state, tcache=dict(state.tcache, bt=jnp.asarray(
-                    pk.device_tables(exclude_pending=True))))
-        state = self._drain_evicted(state)
-        B = int(state.seq_lens.shape[0])
-        CB, _, _, L, R = chunk.key
-        key = (B, s, CB, L, R)
-        if key not in self._mixed_step_fns:
-            self._mixed_step_fns[key] = self._build_step_mixed(B, s, CB,
-                                                               L, R)
-        # the grid plan covers the chunk row's blocks through the patched
-        # table (the kernel reads them via bt_eff, not the device bt)
-        tables = pk.device_tables(exclude_pending=True)
-        tables[chunk.slot] = chunk.bt_row
-        cu = host_cu_blocks(tables)
-        feed_total = chunk.total_len - 1
-        args = (tparams, dparams, state.tcache, state.dcache,
-                state.seq_lens, state.last2, state.out, state.n_generated,
-                state.done, jnp.asarray(cu), jnp.int32(chunk.slot),
-                jnp.asarray(chunk.tokens), jnp.int32(chunk.start),
-                jnp.int32(feed_total), jnp.int32(feed_total - 1),
-                jnp.asarray(chunk.bt_row))
+        with self.scope("step.prep"):
+            grew = False
+            for slot in pk.active_slots():
+                if pk.is_pending(slot):
+                    continue
+                grew |= bool(pk.ensure(slot, pk.tokens(slot) + s))
+            if grew:
+                state = dataclasses.replace(
+                    state, tcache=dict(state.tcache, bt=jnp.asarray(
+                        pk.device_tables(exclude_pending=True))))
+            state = self._drain_evicted(state)
+            B = int(state.seq_lens.shape[0])
+            CB, _, _, L, R = chunk.key
+            key = (B, s, CB, L, R)
+            if key not in self._mixed_step_fns:
+                self._mixed_step_fns[key] = self._build_step_mixed(B, s, CB,
+                                                                   L, R)
+            # the grid plan covers the chunk row's blocks through the
+            # patched table (the kernel reads them via bt_eff, not the
+            # device bt)
+            tables = pk.device_tables(exclude_pending=True)
+            tables[chunk.slot] = chunk.bt_row
+            cu = host_cu_blocks(tables)
+            feed_total = chunk.total_len - 1
+            args = (tparams, dparams, state.tcache, state.dcache,
+                    state.seq_lens, state.last2, state.out,
+                    state.n_generated, state.done, jnp.asarray(cu),
+                    jnp.int32(chunk.slot), jnp.asarray(chunk.tokens),
+                    jnp.int32(chunk.start), jnp.int32(feed_total),
+                    jnp.int32(feed_total - 1), jnp.asarray(chunk.bt_row))
         if self.sample:
             if rng is None:
-                rng = jax.random.PRNGKey(
-                    int(np.asarray(state.n_generated).sum()))
+                with self.scope("step.key"):
+                    rng = jax.random.PRNGKey(
+                        int(np.asarray(state.n_generated).sum()))
             args = (*args, rng)
-        with (jax.profiler.TraceAnnotation(
-                f"repro/step_mixed[B={B},s={s},CB={CB}]")
-              if self.annotate else _NULLCTX):
+        with self.scope("step_mixed[B={},s={},CB={}]", B, s, CB):
             (tc, dcache, seq_lens, last2, out, n_gen, done, a, n_commit) = \
                 self._mixed_step_fns[key](*args)
         new_state = DecodeState(tc, dcache, seq_lens, last2, out, n_gen,
                                 done, paged=pk)
-        stats = StepStats(accepted=np.asarray(a), committed=np.asarray(n_commit))
-        for slot in pk.active_slots():
-            if not pk.is_pending(slot):
-                pk.commit(slot, int(stats.committed[slot]))
+        with self.scope("step.wait"):
+            stats = StepStats(accepted=np.asarray(a),
+                              committed=np.asarray(n_commit))
+        with self.scope("step.commit"):
+            for slot in pk.active_slots():
+                if not pk.is_pending(slot):
+                    pk.commit(slot, int(stats.committed[slot]))
         return new_state, stats
 
     # ------------------------------------------------------------------
@@ -1632,63 +1656,69 @@ class SpecDecodeEngine:
                 f"s={s} outside [0, {S_MAX}]: the step's output buffer is "
                 f"sized for at most S_MAX={S_MAX} speculative tokens and "
                 f"would silently drop commits beyond it")
-        if state.paged is not None and not warm:
-            pk = state.paged
-            grew = False
-            for slot in pk.active_slots():
-                if pk.is_pending(slot):
-                    # mid-chunked-prefill: the slot is parked done, writes
-                    # nothing this step, and grows only when its next chunk
-                    # is fed (prefill_chunk_into allocates those blocks)
-                    continue
-                grew |= bool(pk.ensure(slot, pk.tokens(slot) + s))
-            if grew:
-                # prefill_into/retire_slot keep the device table in sync, so
-                # the host->device upload only happens on actual growth.
-                # Pending (mid-chunked-prefill) slots' rows stay -1 so their
-                # parked rows' masked decode writes remain dropped; their
-                # blocks are published by the final chunk's commit.
-                state = dataclasses.replace(
-                    state, tcache=dict(state.tcache, bt=jnp.asarray(
-                        pk.device_tables(exclude_pending=True))))
-            state = self._drain_evicted(state)
-        B = state.seq_lens.shape[0]
-        # pagedness is part of the key: the paged wrapper takes the extra
-        # cu_blocks operand, so a contiguous pool on the same engine must
-        # never reuse a paged-built step fn (or vice versa)
-        key = (B, s, state.paged is not None)
-        if key not in self._step_fns:
-            self._step_fns[key] = self._build_step(
-                B, s, paged_rows=(state.paged.logical_len
-                                  if state.paged is not None else None))
-        if warm:
-            state = self._warm_shield(state)
-        args = (tparams, dparams, state.tcache, state.dcache, state.seq_lens,
-                state.last2, state.out, state.n_generated, state.done)
-        if state.paged is not None:
-            # ragged-grid operand: cumulative live-block counts from the
-            # same host tables the device `bt` upload above mirrors, so the
-            # kernel's grid always matches the table it prefetches
-            cu = host_cu_blocks(
-                state.paged.device_tables(exclude_pending=True))
-            args = (*args, jnp.asarray(cu))
+        with self.scope("step.prep"):
+            if state.paged is not None and not warm:
+                pk = state.paged
+                grew = False
+                for slot in pk.active_slots():
+                    if pk.is_pending(slot):
+                        # mid-chunked-prefill: the slot is parked done,
+                        # writes nothing this step, and grows only when its
+                        # next chunk is fed (prefill_chunk_into allocates
+                        # those blocks)
+                        continue
+                    grew |= bool(pk.ensure(slot, pk.tokens(slot) + s))
+                if grew:
+                    # prefill_into/retire_slot keep the device table in
+                    # sync, so the host->device upload only happens on
+                    # actual growth.  Pending (mid-chunked-prefill) slots'
+                    # rows stay -1 so their parked rows' masked decode
+                    # writes remain dropped; their blocks are published by
+                    # the final chunk's commit.
+                    state = dataclasses.replace(
+                        state, tcache=dict(state.tcache, bt=jnp.asarray(
+                            pk.device_tables(exclude_pending=True))))
+                state = self._drain_evicted(state)
+            B = state.seq_lens.shape[0]
+            # pagedness is part of the key: the paged wrapper takes the
+            # extra cu_blocks operand, so a contiguous pool on the same
+            # engine must never reuse a paged-built step fn (or vice versa)
+            key = (B, s, state.paged is not None)
+            if key not in self._step_fns:
+                self._step_fns[key] = self._build_step(
+                    B, s, paged_rows=(state.paged.logical_len
+                                      if state.paged is not None else None))
+            if warm:
+                state = self._warm_shield(state)
+            args = (tparams, dparams, state.tcache, state.dcache,
+                    state.seq_lens, state.last2, state.out,
+                    state.n_generated, state.done)
+            if state.paged is not None:
+                # ragged-grid operand: cumulative live-block counts from the
+                # same host tables the device `bt` upload above mirrors, so
+                # the kernel's grid always matches the table it prefetches
+                cu = host_cu_blocks(
+                    state.paged.device_tables(exclude_pending=True))
+                args = (*args, jnp.asarray(cu))
         if self.sample:
             if rng is None:
-                # lint: allow-host-sync(sample-mode fallback seed only; serving passes rng explicitly)
-                rng = jax.random.PRNGKey(int(np.asarray(state.n_generated).sum()))
+                with self.scope("step.key"):
+                    # lint: allow-host-sync(the sampled step's key: the pool's n_generated sum, when the caller passes no rng)
+                    rng = jax.random.PRNGKey(int(np.asarray(state.n_generated).sum()))
             args = (*args, rng)
-        with (jax.profiler.TraceAnnotation(f"repro/step[B={B},s={s}]")
-              if self.annotate else _NULLCTX):
+        with self.scope("step[B={},s={}]", B, s):
             (tc, dc, seq_lens, last2, out, n_gen, done, a, n_commit) = \
                 self._step_fns[key](*args)
         new_state = DecodeState(tc, dc, seq_lens, last2, out, n_gen, done,
                                 paged=state.paged)
-        # lint: allow-host-sync(step-boundary sync: commit counts drive host-side block accounting)
-        stats = StepStats(accepted=np.asarray(a), committed=np.asarray(n_commit))
+        with self.scope("step.wait"):
+            # lint: allow-host-sync(step-boundary sync: commit counts drive host-side block accounting)
+            stats = StepStats(accepted=np.asarray(a), committed=np.asarray(n_commit))
         if state.paged is not None and not warm:
-            for slot in state.paged.active_slots():
-                if not state.paged.is_pending(slot):
-                    state.paged.commit(slot, int(stats.committed[slot]))
+            with self.scope("step.commit"):
+                for slot in state.paged.active_slots():
+                    if not state.paged.is_pending(slot):
+                        state.paged.commit(slot, int(stats.committed[slot]))
         return new_state, stats
 
     # ------------------------------------------------------------------

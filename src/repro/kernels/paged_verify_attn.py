@@ -286,6 +286,7 @@ def paged_verify_attn_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     )
     o = pl.pallas_call(
         kern,
+        name="paged_verify_attn",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, GT, hd), q.dtype),
         interpret=interpret,
@@ -586,6 +587,7 @@ def ragged_paged_verify_attn_pallas(q: jax.Array, k: jax.Array,
         )
     o = pl.pallas_call(
         kern,
+        name="ragged_paged_verify_attn",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, GT, hd), q.dtype),
         interpret=interpret,

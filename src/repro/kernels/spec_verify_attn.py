@@ -185,6 +185,7 @@ def spec_verify_attn_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                                   nk=nk, ks_ref=ks_ref, vs_ref=vs_ref)
     return pl.pallas_call(
         kern,
+        name="spec_verify_attn",
         grid=(B, nk),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, Tq, hd), lambda b, j: (b, 0, 0)),

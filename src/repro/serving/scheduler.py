@@ -78,6 +78,7 @@ from repro.kernels.tuning import grid_steps_dense, grid_steps_ragged
 from repro.serving.acceptance import GeometricAcceptance
 from repro.serving.request import BatchRecord, Request
 from repro.serving.slots import PagedKVTables, SlotPool
+from repro.serving.telemetry import NULLSPAN, span
 
 
 # ---------------------------------------------------------------------------
@@ -411,6 +412,12 @@ class ContinuousEngineBackend:
         return self.cache_len
 
     @property
+    def annotate(self) -> bool:
+        """The span switch (the engine's ``annotate``); the scheduler reads
+        it once per iteration."""
+        return self.engine.annotate
+
+    @property
     def can_chunk(self) -> bool:
         """Whether the engine's model pair supports chunked prefill."""
         eng = self.engine
@@ -455,24 +462,28 @@ class ContinuousEngineBackend:
 
     def prefill(self, req: Request, slot: int) -> float:
         """Inject ``req`` into ``slot``; returns seconds of prefill work."""
-        _reject_oversize(req, self.max_context, self.s_cap)  # defense in depth
-        self._flush_deferred()
-        prompt = self._full_prompt(req)
-        plen = len(prompt)
-        P = self._bucket(plen)
-        toks = np.ones((P,), np.int32)
-        toks[:plen] = prompt
-        if P not in self._warm_prefill:
-            # compile the B=1 prefill + inject for this bucket off the clock
-            self.engine.prefill_into(self.tparams, self.dparams, self.state,
-                                     slot, toks, plen, self.cache_len,
-                                     warm=True)
-            self._warm_prefill.add(P)
+        scope = self.engine.scope
+        with scope("prefill.prep"):
+            _reject_oversize(req, self.max_context, self.s_cap)  # defense in depth
+            self._flush_deferred()
+            prompt = self._full_prompt(req)
+            plen = len(prompt)
+            P = self._bucket(plen)
+            toks = np.ones((P,), np.int32)
+            toks[:plen] = prompt
+            if P not in self._warm_prefill:
+                # compile the B=1 prefill + inject for this bucket off the
+                # clock
+                self.engine.prefill_into(self.tparams, self.dparams,
+                                         self.state, slot, toks, plen,
+                                         self.cache_len, warm=True)
+                self._warm_prefill.add(P)
         t0 = time.perf_counter()
         self.state = self.engine.prefill_into(
             self.tparams, self.dparams, self.state, slot, toks,
             plen, self.cache_len)
-        np.asarray(self.state.seq_lens)  # lint: allow-host-sync(deliberate fence: prefill wall-clock timing)
+        with scope("prefill.fence"):
+            np.asarray(self.state.seq_lens)  # lint: allow-host-sync(deliberate fence: prefill wall-clock timing)
         return time.perf_counter() - t0
 
     def prefill_chunk(self, req: Request, slot: int, start: int,
@@ -485,22 +496,24 @@ class ContinuousEngineBackend:
         prefill); the chunk carrying the final position also commits the
         slot into the decode batch.
         """
-        if start == 0:
-            _reject_oversize(req, self.max_context, self.s_cap)
-        self._flush_deferred()
-        prompt = self._full_prompt(req)
-        total_len = len(prompt)
-        feed_total = total_len - 1
-        CB = self._bucket(n)
-        toks = np.ones((CB,), np.int32)
-        toks[:n] = prompt[start:start + n]
-        final = start + n == feed_total
-        if CB not in self._warm_chunk:
-            # compile begin/chunk/commit for this bucket off the clock
-            self.engine.prefill_chunk_into(
-                self.tparams, self.dparams, self.state, slot,
-                np.ones((CB,), np.int32), 0, CB, CB + 2, warm=True)
-            self._warm_chunk.add(CB)
+        scope = self.engine.scope
+        with scope("chunk.prep"):
+            if start == 0:
+                _reject_oversize(req, self.max_context, self.s_cap)
+            self._flush_deferred()
+            prompt = self._full_prompt(req)
+            total_len = len(prompt)
+            feed_total = total_len - 1
+            CB = self._bucket(n)
+            toks = np.ones((CB,), np.int32)
+            toks[:n] = prompt[start:start + n]
+            final = start + n == feed_total
+            if CB not in self._warm_chunk:
+                # compile begin/chunk/commit for this bucket off the clock
+                self.engine.prefill_chunk_into(
+                    self.tparams, self.dparams, self.state, slot,
+                    np.ones((CB,), np.int32), 0, CB, CB + 2, warm=True)
+                self._warm_chunk.add(CB)
         if self.mixed_launch and not final:
             # defer the forward: host bookkeeping runs now (block accounting
             # and admission decisions are unchanged), the dispatch rides the
@@ -515,7 +528,8 @@ class ContinuousEngineBackend:
         self.state = self.engine.prefill_chunk_into(
             self.tparams, self.dparams, self.state, slot, toks, start, n,
             total_len, last2=prompt[-2:] if final else None)
-        np.asarray(self.state.seq_lens)  # lint: allow-host-sync(deliberate fence: chunk wall-clock timing)
+        with scope("chunk.fence"):
+            np.asarray(self.state.seq_lens)  # lint: allow-host-sync(deliberate fence: chunk wall-clock timing)
         return time.perf_counter() - t0
 
     # ------------------------------------------------------------------
@@ -548,40 +562,47 @@ class ContinuousEngineBackend:
         the slot, and run the draft-only prefix prefill; returns seconds.
         The uncached suffix is then fed via :meth:`prefill_chunk` with
         ``start = n_prefix`` (or, zero-suffix, :meth:`commit_attached`)."""
-        self._flush_deferred()
-        blocks = self._locked.pop(req.rid)
-        prompt = self._full_prompt(req)
-        total_len = len(prompt)
-        self.kv.attach(slot, blocks, n_prefix)
-        self.cache.unlock(blocks)      # the slot now holds its own refs
-        P = self._bucket(total_len)
-        toks = np.ones((P,), np.int32)
-        toks[:total_len] = prompt
-        if P not in self._warm_attach:
-            self.engine.attach_prefix(self.dparams, self.state, slot, toks,
-                                      n_prefix, total_len, warm=True)
-            self._warm_attach.add(P)
+        scope = self.engine.scope
+        with scope("attach.prep"):
+            self._flush_deferred()
+            blocks = self._locked.pop(req.rid)
+            prompt = self._full_prompt(req)
+            total_len = len(prompt)
+            self.kv.attach(slot, blocks, n_prefix)
+            self.cache.unlock(blocks)      # the slot now holds its own refs
+            P = self._bucket(total_len)
+            toks = np.ones((P,), np.int32)
+            toks[:total_len] = prompt
+            if P not in self._warm_attach:
+                self.engine.attach_prefix(self.dparams, self.state, slot,
+                                          toks, n_prefix, total_len,
+                                          warm=True)
+                self._warm_attach.add(P)
         t0 = time.perf_counter()
         self.state = self.engine.attach_prefix(
             self.dparams, self.state, slot, toks, n_prefix, total_len)
-        np.asarray(self.state.seq_lens)  # lint: allow-host-sync(deliberate fence: attach wall-clock timing)
+        with scope("attach.fence"):
+            np.asarray(self.state.seq_lens)  # lint: allow-host-sync(deliberate fence: attach wall-clock timing)
         return time.perf_counter() - t0
 
     def commit_attached(self, req: Request, slot: int) -> float:
         """Commit a fully-cached attach into the decode batch (no prefill
         forward at all — COW of the last block if needed, then the ordinary
         chunk-commit).  Returns seconds."""
-        self._flush_deferred()
-        prompt = self._full_prompt(req)
-        total_len = len(prompt)
-        if not self._warm_commit_attached:
-            self.engine.commit_attached(self.state, slot, total_len,
-                                        prompt[-2:], warm=True)
-            self._warm_commit_attached = True
+        scope = self.engine.scope
+        with scope("attach.prep"):
+            self._flush_deferred()
+            prompt = self._full_prompt(req)
+            total_len = len(prompt)
+            if not self._warm_commit_attached:
+                self.engine.commit_attached(self.state, slot, total_len,
+                                            prompt[-2:], warm=True)
+                self._warm_commit_attached = True
         t0 = time.perf_counter()
         self.state = self.engine.commit_attached(self.state, slot, total_len,
                                                  prompt[-2:])
-        np.asarray(self.state.seq_lens)  # lint: allow-host-sync(deliberate fence: attach-commit wall-clock timing)
+        with scope("attach.fence"):
+            np.asarray(self.state.seq_lens)  # lint: allow-host-sync(deliberate fence: attach-commit wall-clock timing)
         return time.perf_counter() - t0
 
     def cache_insert(self, req: Request, slot: int) -> None:
@@ -612,30 +633,29 @@ class ContinuousEngineBackend:
         ride the same ragged attention grid as the verify queries instead
         of paying a second kernel launch and weight re-stream.
         """
-        self.warm_step(s)
-        if self._deferred is not None:
+        scope = self.engine.scope
+        with scope("step.prep"):
+            self.warm_step(s)
             chunk, self._deferred = self._deferred, None
-            t0 = time.perf_counter()
+        t0 = time.perf_counter()
+        if chunk is not None:
             self.state, st = self.engine.step_with_chunk(
                 self.tparams, self.dparams, self.state, s, chunk)
-            committed = np.asarray(st.committed)  # lint: allow-host-sync(step boundary: commit counts steer the scheduler)
-            dt = time.perf_counter() - t0
-            # lint: allow-host-sync(step boundary: done flags steer retirement)
-            return dt, committed, np.asarray(self.state.done)
-        t0 = time.perf_counter()
-        self.state, st = self.engine.step(self.tparams, self.dparams,
-                                          self.state, s)
-        committed = np.asarray(st.committed)  # lint: allow-host-sync(step boundary: commit counts steer the scheduler)
+        else:
+            self.state, st = self.engine.step(self.tparams, self.dparams,
+                                              self.state, s)
         dt = time.perf_counter() - t0
-        # lint: allow-host-sync(step boundary: done flags steer retirement)
-        return dt, committed, np.asarray(self.state.done)
+        with scope("step.done"):
+            # lint: allow-host-sync(step boundary: done flags steer retirement)
+            return dt, st.committed, np.asarray(self.state.done)
 
     def preempt(self, slot: int, req: Request) -> None:
         """Evict ``req`` under memory pressure: stash its generated tokens,
         free the slot's KV blocks, and mark the row done."""
         self._flush_deferred()
-        dev_n = int(np.asarray(self.state.n_generated)[slot])  # lint: allow-host-sync(preempt is off the steady path; must read victim count)
-        fresh = np.asarray(self.state.out)[slot, :dev_n].astype(np.int32)  # lint: allow-host-sync(victim tokens are stashed host-side)
+        with self.engine.scope("preempt.out"):
+            dev_n = int(np.asarray(self.state.n_generated)[slot])  # lint: allow-host-sync(preempt is off the steady path; must read victim count)
+            fresh = np.asarray(self.state.out)[slot, :dev_n].astype(np.int32)  # lint: allow-host-sync(victim tokens are stashed host-side)
         old = self._stash.get(req.rid)
         self._stash[req.rid] = (fresh if old is None
                                 else np.concatenate([old, fresh]))
@@ -662,7 +682,8 @@ class ContinuousEngineBackend:
         stash; without it, the legacy engine-sized row is returned.
         """
         self._flush_deferred()
-        out = np.asarray(self.state.out)[slot]
+        with self.engine.scope("retire.out"):
+            out = np.asarray(self.state.out)[slot]
         if req is None:
             return out[:self.engine.max_new]
         stash = self._stash.get(req.rid)
@@ -1012,10 +1033,10 @@ class ContinuousScheduler:
         self.policy = policy or ImmediateAdmit()
         self.observe = observe
         self.telemetry = telemetry
-        # zero-overhead-when-off: every hook in run() fires through _tel,
-        # which is None unless an *enabled* hub was supplied — a disabled
-        # (or absent) hub leaves the hot path with no telemetry branches,
-        # no perf_counter calls, and no event construction
+        # zero-overhead-when-off: _tel is None unless an *enabled* hub was
+        # supplied, and with it None and the backend's span switch off,
+        # every span of run() is the shared no-op NULLSPAN — no
+        # perf_counter calls and no event construction
         self._tel = (telemetry if telemetry is not None
                      and getattr(telemetry, "enabled", True) else None)
         if (self._tel is not None
@@ -1091,8 +1112,17 @@ class ContinuousScheduler:
             return tot
 
         tel = self._tel
+        on = False            # the span switch, read once per iteration
+
+        def scope(phase: str, **attrs):
+            """A span of this iteration (telemetry.span)."""
+            if tel is None and not on:
+                return NULLSPAN
+            return span(phase, tel, on, iter=len(self.trace), **attrs)
+
         clock, i, n_done, n = 0.0, 0, 0, len(pending)
         while n_done < n:
+            on = getattr(self.backend, "annotate", False)
             while i < n and pending[i].arrival <= clock:
                 backlog.append(pending[i])
                 i += 1
@@ -1111,7 +1141,11 @@ class ContinuousScheduler:
                 # no extra replay channel for attach costs
                 nonlocal clock
                 start = req.prefill_pos
-                dt = self.backend.prefill_chunk(req, slot, start, m) + extra
+                with scope("sched.chunk_continue", rid=req.rid, slot=slot,
+                           start=start, n=m) as sp:
+                    dt = (self.backend.prefill_chunk(req, slot, start, m)
+                          + extra)
+                    sp.set(dt=dt)
                 clock += dt
                 chunked.append((req.rid, m))
                 chunk_s.append(dt)
@@ -1121,9 +1155,6 @@ class ContinuousScheduler:
                     # final chunk: the slot joins the decode batch — publish
                     # its prompt blocks into the prefix index
                     self.backend.cache_insert(req, slot)
-                if tel is not None:
-                    tel.span("chunk_continue", len(self.trace), dt,
-                             rid=req.rid, slot=slot, start=start, n=m)
 
             def claim_for(req: Request) -> int:
                 """Shared admission bookkeeping (both admission modes)."""
@@ -1151,165 +1182,168 @@ class ContinuousScheduler:
                 a_dt = self.backend.attach(req, slot, P)
                 req.prefill_pos = P
                 if P >= feed_total:
-                    c_dt = self.backend.commit_attached(req, slot)
+                    with scope("sched.prefill", rid=req.rid, slot=slot,
+                               tokens=total_len - P, cached=P) as sp:
+                        c_dt = self.backend.commit_attached(req, slot)
+                        sp.set(dt=a_dt + c_dt)
                     clock += a_dt + c_dt
                     prefill_s.append(a_dt + c_dt)
                     self.backend.cache_insert(req, slot)
-                    if tel is not None:
-                        tel.span("prefill", len(self.trace), a_dt + c_dt,
-                                 rid=req.rid, slot=slot,
-                                 tokens=total_len - P, cached=P)
                 else:
                     prefill_s.append(-1.0)
                     feed_chunk(req, slot, suffix_chunk, extra=a_dt)
 
-            # ---- continue in-flight chunked prefills (Sarathi: ongoing
-            # prefills spend the budget before new admissions) ----
-            if chunking and prefilling:
-                for slot in sorted(prefilling,
-                                   key=lambda sl: admit_seq[
-                                       prefilling[sl].rid]):
-                    if budget_left <= 0:
-                        break
-                    req = prefilling[slot]
-                    feed_total = req.prompt_len + req.n_generated - 1
-                    start = req.prefill_pos
-                    m = int(min(chunk_cfg, feed_total - start, budget_left))
-                    if kv is not None:
-                        # blocks actually available to this chunk right now
-                        # (free + reclaimable cache-only blocks: the pool
-                        # evicts on demand when the free list runs short)
-                        avail = (kv.available_blocks - growth_reserve(s_cap)
-                                 - pending_reserve(exclude=slot))
-                        cap_rows = ((kv.allocated(slot) + avail)
-                                    * kv.block_size - start)
-                        if cap_rows < feed_total - start + 1:
-                            # full completion (incl. the +1 commit row) does
-                            # not fit yet: feed what fits, short of the
-                            # final position
-                            m = min(m, max(cap_rows, 0),
-                                    feed_total - start - 1)
-                    if m <= 0:
-                        continue       # blocked on blocks; retry next step
-                    feed_chunk(req, slot, m)
-                    budget_left -= m
-                    if req.prefill_pos == feed_total:
-                        del prefilling[slot]
-            # ---- admissions ----
-            if chunking:
-                # budgeted admission supersedes policy.select(): its
-                # whole-prompt budget semantics (skip over-budget heads)
-                # exist precisely because chunk-incapable backends cannot
-                # split a prompt — here an over-budget prompt is admitted
-                # chunked instead, in the same FCFS order select() uses
-                for req in list(backlog):
-                    if pool.free_count == 0 or budget_left <= 0:
-                        break
-                    if max_ctx is not None:
-                        _reject_oversize(req, max_ctx, s_cap)
-                    total_len = req.prompt_len + req.n_generated
-                    # longest cached prefix of the prompt, pinned against
-                    # eviction until attach (or the break below)
-                    P = (self.backend.match_and_lock(req) if cache_on
-                         else 0)
-                    if kv is not None:
-                        # reserve the full prompt + first-step worst case up
-                        # front (plus the running batch's growth and the
-                        # other pending prefills' completion) — a chunked
-                        # admission that could not finish would hold blocks
-                        # forever.  A cache hit attaches P // block_size
-                        # blocks for free (+1 only for the COW copy when the
-                        # whole prompt is cached); reclaimable cache-only
-                        # blocks count as available (eviction on demand).
-                        need = (kv.blocks_for(total_len + s_cap)
-                                - P // kv.block_size
-                                + (1 if P == total_len else 0))
-                        if (need + growth_reserve(s_cap) + pending_reserve()
-                                > kv.available_blocks):
-                            if P:
-                                self.backend.cancel_match(req)
-                            break      # head-of-line: wait for free blocks
-                    slot = claim_for(req)
-                    req.prefill_pos = 0
-                    if P:
-                        feed_total = total_len - 1
-                        if P >= feed_total:
-                            attach_admit(req, slot, P, 0)
+            with scope("sched.admit") as adm:
+                # ---- continue in-flight chunked prefills (Sarathi: ongoing
+                # prefills spend the budget before new admissions) ----
+                if chunking and prefilling:
+                    for slot in sorted(prefilling,
+                                       key=lambda sl: admit_seq[
+                                           prefilling[sl].rid]):
+                        if budget_left <= 0:
+                            break
+                        req = prefilling[slot]
+                        feed_total = req.prompt_len + req.n_generated - 1
+                        start = req.prefill_pos
+                        m = int(min(chunk_cfg, feed_total - start,
+                                    budget_left))
+                        if kv is not None:
+                            # blocks actually available to this chunk right now
+                            # (free + reclaimable cache-only blocks: the pool
+                            # evicts on demand when the free list runs short)
+                            avail = (kv.available_blocks
+                                     - growth_reserve(s_cap)
+                                     - pending_reserve(exclude=slot))
+                            cap_rows = ((kv.allocated(slot) + avail)
+                                        * kv.block_size - start)
+                            if cap_rows < feed_total - start + 1:
+                                # full completion (incl. the +1 commit row)
+                                # does not fit yet: feed what fits, short of
+                                # the final position
+                                m = min(m, max(cap_rows, 0),
+                                        feed_total - start - 1)
+                        if m <= 0:
+                            continue       # blocked on blocks; retry next step
+                        feed_chunk(req, slot, m)
+                        budget_left -= m
+                        if req.prefill_pos == feed_total:
+                            del prefilling[slot]
+                # ---- admissions ----
+                if chunking:
+                    # budgeted admission supersedes policy.select(): its
+                    # whole-prompt budget semantics (skip over-budget heads)
+                    # exist precisely because chunk-incapable backends cannot
+                    # split a prompt — here an over-budget prompt is admitted
+                    # chunked instead, in the same FCFS order select() uses
+                    for req in list(backlog):
+                        if pool.free_count == 0 or budget_left <= 0:
+                            break
+                        if max_ctx is not None:
+                            _reject_oversize(req, max_ctx, s_cap)
+                        total_len = req.prompt_len + req.n_generated
+                        # longest cached prefix of the prompt, pinned against
+                        # eviction until attach (or the break below)
+                        P = (self.backend.match_and_lock(req) if cache_on
+                             else 0)
+                        if kv is not None:
+                            # reserve the full prompt + first-step worst case
+                            # up front (plus the running batch's growth and
+                            # the other pending prefills' completion) — a
+                            # chunked admission that could not finish would
+                            # hold blocks forever.  A cache hit attaches
+                            # P // block_size blocks for free (+1 only for the
+                            # COW copy when the whole prompt is cached);
+                            # reclaimable cache-only blocks count as
+                            # available (eviction on demand).
+                            need = (kv.blocks_for(total_len + s_cap)
+                                    - P // kv.block_size
+                                    + (1 if P == total_len else 0))
+                            if (need + growth_reserve(s_cap)
+                                    + pending_reserve()
+                                    > kv.available_blocks):
+                                if P:
+                                    self.backend.cancel_match(req)
+                                break      # head-of-line: wait for free blocks
+                        slot = claim_for(req)
+                        req.prefill_pos = 0
+                        if P:
+                            feed_total = total_len - 1
+                            if P >= feed_total:
+                                attach_admit(req, slot, P, 0)
+                            else:
+                                m = int(min(chunk_cfg, budget_left,
+                                            feed_total - P))
+                                attach_admit(req, slot, P, m)
+                                budget_left -= m
+                                if req.prefill_pos < feed_total:
+                                    prefilling[slot] = req
+                        elif total_len <= budget_left:
+                            with scope("sched.prefill", rid=req.rid, slot=slot,
+                                       tokens=total_len) as sp:
+                                p_dt = self.backend.prefill(req, slot)
+                                sp.set(dt=p_dt)
+                            clock += p_dt
+                            prefill_s.append(p_dt)
+                            budget_left -= total_len
+                            if cache_on:
+                                self.backend.cache_insert(req, slot)
                         else:
-                            m = int(min(chunk_cfg, budget_left,
-                                        feed_total - P))
-                            attach_admit(req, slot, P, m)
+                            # over the remaining budget: admit CHUNKED —
+                            # never a whole-prompt burst (bounds this
+                            # iteration's stall)
+                            prefill_s.append(-1.0)
+                            feed_total = total_len - 1
+                            m = int(min(chunk_cfg, budget_left, feed_total))
+                            feed_chunk(req, slot, m)
                             budget_left -= m
                             if req.prefill_pos < feed_total:
                                 prefilling[slot] = req
-                    elif total_len <= budget_left:
-                        p_dt = self.backend.prefill(req, slot)
+                else:
+                    for req in self.policy.select(backlog, pool.free_count,
+                                                  clock):
+                        if max_ctx is not None:
+                            # oversized requests can NEVER be served (deferring
+                            # would spin forever); fail loudly before claiming
+                            _reject_oversize(req, max_ctx, s_cap)
+                        total_len = req.prompt_len + req.n_generated
+                        P = (self.backend.match_and_lock(req) if cache_on
+                             else 0)
+                        if kv is not None:
+                            # admit only if the free list covers the prompt
+                            # (plus stash), this request's worst-case first
+                            # step, AND the running batch's own worst-case
+                            # growth — otherwise a fresh admit pays a full
+                            # B=1 prefill just to be evicted by the pressure
+                            # check below (prefill thrash).  Cache hits and
+                            # reclaimable blocks discount as in the chunked
+                            # branch above.
+                            need = (kv.blocks_for(total_len + s_cap)
+                                    - P // kv.block_size
+                                    + (1 if P == total_len else 0))
+                            if (need + growth_reserve(s_cap)
+                                    > kv.available_blocks):
+                                if P:
+                                    self.backend.cancel_match(req)
+                                break      # head-of-line: wait for free blocks
+                        slot = claim_for(req)
+                        if P:
+                            # no per-iteration budget here: any uncached suffix
+                            # is fed as one chunk (cache_on implies can_chunk)
+                            req.prefill_pos = 0
+                            attach_admit(req, slot, P, total_len - 1 - P)
+                            continue
+                        with scope("sched.prefill", rid=req.rid, slot=slot,
+                                   tokens=total_len) as sp:
+                            p_dt = self.backend.prefill(req, slot)
+                            sp.set(dt=p_dt)
                         clock += p_dt
                         prefill_s.append(p_dt)
-                        budget_left -= total_len
                         if cache_on:
                             self.backend.cache_insert(req, slot)
-                        if tel is not None:
-                            tel.span("prefill", len(self.trace), p_dt,
-                                     rid=req.rid, slot=slot,
-                                     tokens=total_len)
-                    else:
-                        # over the remaining budget: admit CHUNKED — never a
-                        # whole-prompt burst (bounds this iteration's stall)
-                        prefill_s.append(-1.0)
-                        feed_total = total_len - 1
-                        m = int(min(chunk_cfg, budget_left, feed_total))
-                        feed_chunk(req, slot, m)
-                        budget_left -= m
-                        if req.prefill_pos < feed_total:
-                            prefilling[slot] = req
-            else:
-                for req in self.policy.select(backlog, pool.free_count,
-                                              clock):
-                    if max_ctx is not None:
-                        # oversized requests can NEVER be served (deferring
-                        # would spin forever); fail loudly before claiming
-                        _reject_oversize(req, max_ctx, s_cap)
-                    total_len = req.prompt_len + req.n_generated
-                    P = (self.backend.match_and_lock(req) if cache_on
-                         else 0)
-                    if kv is not None:
-                        # admit only if the free list covers the prompt
-                        # (plus stash), this request's worst-case first
-                        # step, AND the running batch's own worst-case
-                        # growth — otherwise a fresh admit pays a full B=1
-                        # prefill just to be evicted by the pressure check
-                        # below (prefill thrash).  Cache hits and
-                        # reclaimable blocks discount as in the chunked
-                        # branch above.
-                        need = (kv.blocks_for(total_len + s_cap)
-                                - P // kv.block_size
-                                + (1 if P == total_len else 0))
-                        if need + growth_reserve(s_cap) > kv.available_blocks:
-                            if P:
-                                self.backend.cancel_match(req)
-                            break      # head-of-line: wait for free blocks
-                    slot = claim_for(req)
-                    if P:
-                        # no per-iteration budget here: any uncached suffix
-                        # is fed as one chunk (cache_on implies can_chunk)
-                        req.prefill_pos = 0
-                        attach_admit(req, slot, P, total_len - 1 - P)
-                        continue
-                    p_dt = self.backend.prefill(req, slot)
-                    clock += p_dt
-                    prefill_s.append(p_dt)
-                    if cache_on:
-                        self.backend.cache_insert(req, slot)
-                    if tel is not None:
-                        tel.span("prefill", len(self.trace), p_dt,
-                                 rid=req.rid, slot=slot,
-                                 tokens=total_len)
-            if tel is not None and admitted:
-                tel.span("admit", len(self.trace),
-                         sum(dt for dt in prefill_s if dt > 0),
-                         rids=tuple(admitted),
-                         n_chunked=sum(1 for dt in prefill_s if dt < 0))
+                if admitted:
+                    adm.set(dt=sum(dt for dt in prefill_s if dt > 0),
+                            rids=tuple(admitted),
+                            n_chunked=sum(1 for dt in prefill_s if dt < 0))
             if pool.occupancy == 0:
                 if not backlog and i < n:
                     clock = max(clock, pending[i].arrival)
@@ -1321,87 +1355,86 @@ class ContinuousScheduler:
             # evict victims back to the backlog (they re-prefill from
             # prompt + generated stash later).  A lone slot always fits:
             # admission bounds every request to the pool.
-            preempted: List[int] = []
-            if kv is not None:
-                while pool.occupancy > 1:
-                    ds = decode_slots()
-                    s = self.controller.choose(len(ds))
-                    need = (growth_reserve(s) + pending_reserve())
-                    if need <= kv.available_blocks:
-                        break
-                    # never evict a slot the backend already flagged done
-                    # (EOS'd, awaiting its zero-commit retirement step):
-                    # re-prefilling it would resurrect a finished request
-                    # and generate past its EOS.  Mid-prefill slots are not
-                    # eligible either: their completion is what the
-                    # reservation protects.
-                    eligible = [sl for sl in ds
-                                if pool.request_at(sl).rid not in prev_done]
-                    if not eligible:
-                        break          # done slots free their blocks shortly
-                    victim = self._select_victim(eligible, pool, admit_seq)
-                    req = pool.retire(victim)
-                    self.backend.preempt(victim, req)
-                    req.prefill_pos = 0
-                    backlog.insert(0, req)
-                    preempted.append(req.rid)
-                    if tel is not None:
-                        tel.span("preempt", len(self.trace), 0.0,
-                                 rid=req.rid, slot=victim,
-                                 n_generated=req.n_generated)
-            ds = decode_slots()
-            b = len(ds)
+            with scope("sched.choose"):
+                preempted: List[int] = []
+                if kv is not None:
+                    while pool.occupancy > 1:
+                        ds = decode_slots()
+                        s = self.controller.choose(len(ds))
+                        need = (growth_reserve(s) + pending_reserve())
+                        if need <= kv.available_blocks:
+                            break
+                        # never evict a slot the backend already flagged
+                        # done (EOS'd, awaiting its zero-commit retirement
+                        # step): re-prefilling it would resurrect a finished
+                        # request and generate past its EOS.  Mid-prefill
+                        # slots are not eligible either: their completion is
+                        # what the reservation protects.
+                        eligible = [sl for sl in ds
+                                    if pool.request_at(sl).rid
+                                    not in prev_done]
+                        if not eligible:
+                            break      # done slots free their blocks shortly
+                        victim = self._select_victim(eligible, pool,
+                                                     admit_seq)
+                        req = pool.retire(victim)
+                        with scope("sched.preempt", rid=req.rid,
+                                   slot=victim, n_generated=req.n_generated):
+                            self.backend.preempt(victim, req)
+                        req.prefill_pos = 0
+                        backlog.insert(0, req)
+                        preempted.append(req.rid)
+                ds = decode_slots()
+                b = len(ds)
+                if b > 0:
+                    s = self.controller.choose(b)
             if b > 0:
-                s = self.controller.choose(b)
-                dt, committed, backend_done = self.backend.step(s)
-                done_rids = tuple(sorted(
-                    pool.request_at(sl).rid for sl in ds
-                    if backend_done[sl]))
-                clock += dt
-                if tel is not None:
-                    tel.span("decode_verify", len(self.trace), dt,
-                             s=s, batch=b)
-                    t_commit0 = time.perf_counter()
-                n_done0 = n_done
-                toks = 0
-                raw: Dict[int, int] = {}
-                accepted_live: List[int] = []
-                for slot in ds:
-                    req = pool.request_at(slot)
-                    c_raw = int(committed[slot])
-                    raw[req.rid] = c_raw
-                    accepted_live.append(max(c_raw - 1, 0))
-                    c = min(c_raw, pool.remaining(slot))
-                    if c > 0 and req.first_token is None:
-                        req.first_token = clock
-                    pool.consume(slot, c)
-                    req.n_generated += c
-                    toks += c
-                    # finished: served its token budget, or the backend
-                    # stopped committing for it (EOS / engine-level max_new)
-                    if pool.remaining(slot) <= 0 or (c_raw == 0
-                                                     and backend_done[slot]):
-                        req.finish = clock
-                        pool.retire(slot)
-                        self.backend.retire(slot, req)
-                        n_done += 1
-                        if tel is not None:
-                            tel.span("retire", len(self.trace), 0.0,
-                                     rid=req.rid, slot=slot,
-                                     n_generated=req.n_generated)
-                if tel is not None:
-                    tel.span("commit", len(self.trace),
-                             time.perf_counter() - t_commit0,
-                             tokens=toks, batch=b, retired=n_done - n_done0)
-                    tel.observe_step(s=s, batch=b, accepted=accepted_live,
-                                     duration=dt)
-                if self.observe and s > 0:
-                    # lint: allow-host-sync(accepted_live is already a host list; no device transfer)
-                    self.controller.observe(np.asarray(accepted_live), s)
-                batches.append(BatchRecord(
-                    start=clock - dt, duration=dt, batch_size=b, s_used=s,
-                    tokens_generated=toks, n_steps=1,
-                    rids=tuple(sorted(raw))))
+                with scope("sched.decode_verify", s=s, batch=b) as sp:
+                    dt, committed, backend_done = self.backend.step(s)
+                    sp.set(dt=dt)
+                with scope("sched.commit", batch=b) as commit_sp:
+                    done_rids = tuple(sorted(
+                        pool.request_at(sl).rid for sl in ds
+                        if backend_done[sl]))
+                    clock += dt
+                    n_done0 = n_done
+                    toks = 0
+                    raw: Dict[int, int] = {}
+                    accepted_live: List[int] = []
+                    for slot in ds:
+                        req = pool.request_at(slot)
+                        c_raw = int(committed[slot])
+                        raw[req.rid] = c_raw
+                        accepted_live.append(max(c_raw - 1, 0))
+                        c = min(c_raw, pool.remaining(slot))
+                        if c > 0 and req.first_token is None:
+                            req.first_token = clock
+                        pool.consume(slot, c)
+                        req.n_generated += c
+                        toks += c
+                        # finished: served its token budget, or the backend
+                        # stopped committing for it (EOS / engine-level
+                        # max_new)
+                        if pool.remaining(slot) <= 0 or (
+                                c_raw == 0 and backend_done[slot]):
+                            req.finish = clock
+                            with scope("sched.retire", rid=req.rid,
+                                       slot=slot,
+                                       n_generated=req.n_generated):
+                                pool.retire(slot)
+                                self.backend.retire(slot, req)
+                            n_done += 1
+                    commit_sp.set(tokens=toks, retired=n_done - n_done0)
+                    if tel is not None:
+                        tel.observe_step(s=s, batch=b,
+                                         accepted=accepted_live, duration=dt)
+                    if self.observe and s > 0:
+                        # lint: allow-host-sync(accepted_live is already a host list; no device transfer)
+                        self.controller.observe(np.asarray(accepted_live), s)
+                    batches.append(BatchRecord(
+                        start=clock - dt, duration=dt, batch_size=b,
+                        s_used=s, tokens_generated=toks, n_steps=1,
+                        rids=tuple(sorted(raw))))
             else:
                 # no live decode row this iteration (all occupied slots are
                 # mid-chunked-prefill): the clock advanced by chunk work only
@@ -1516,13 +1549,16 @@ def serve_continuous_live(requests: Sequence[Request], engine, tparams,
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before importing
     jax to try this without accelerators.
 
-    ``telemetry`` attaches a :class:`repro.serving.telemetry.Telemetry` hub:
-    phase spans, the (s, batch) acceptance observatory, and pool/scheduler
-    gauges, plus — when the hub was built with ``annotate_device`` or
-    ``profile_dir`` — per-phase ``jax.profiler.TraceAnnotation`` scopes on
-    the engine's jit dispatches (and a profiler trace around the run).
-    Telemetry only *reads* the pipeline: token outputs and the StepTrace
-    are identical with it on or off, and a disabled hub costs nothing.
+    ``telemetry`` attaches a :class:`repro.serving.telemetry.Telemetry` hub
+    to the scheduler and the engine for the run: stamped spans of the
+    scheduler, the backend and the engine, the (s, batch) acceptance
+    observatory, and pool/scheduler gauges, plus — when the hub was built
+    with ``annotate_device`` or ``profile_dir`` — the engine's span switch
+    (``engine.annotate``), which puts every span on the profiler timeline as
+    a ``repro/<phase>`` ``TraceAnnotation`` (and a profiler trace around the
+    run).  Telemetry only *reads* the pipeline: token outputs and the
+    StepTrace are identical with it on or off, and a disabled hub costs
+    nothing.
     """
     for r in requests:
         if r.max_new > engine.max_new:
@@ -1580,16 +1616,18 @@ def serve_continuous_live(requests: Sequence[Request], engine, tparams,
     sched = ContinuousScheduler(backend, controller, policy, observe=observe,
                                 telemetry=telemetry)
     tel = sched._tel
-    prev_annotate = getattr(engine, "annotate", False)
-    if tel is not None and tel.annotate_device:
-        engine.annotate = True
+    prev = (getattr(engine, "annotate", False),
+            getattr(engine, "telemetry", None))
     if tel is not None:
+        engine.telemetry = tel
+        if tel.annotate_device:
+            engine.annotate = True
         tel.start()
     try:
         result = sched.run(requests)
     finally:
         if tel is not None:
             tel.stop()
-        engine.annotate = prev_annotate
+        engine.annotate, engine.telemetry = prev
     result.trace = sched.trace
     return result

@@ -1,26 +1,40 @@
-"""Serving telemetry hub: phase-span tracing, the live (s, batch) acceptance
+"""Serving telemetry hub: one span system for the scheduler, the backend
+and the engine, the program's compile log, the live (s, batch) acceptance
 observatory, and pool/scheduler gauges for the continuous-batching runtime.
 
 The hub is strictly **read-only observability**: it never touches the step
 pipeline's decisions, so token outputs and the :class:`StepTrace` are
 bit-identical with telemetry on or off (tests/test_telemetry.py enforces the
 contract on the live engine for the contiguous, paged-under-preemption, and
-chunked-admission paths).  It is also **zero-overhead when off**: the
-scheduler only wires its hooks when an *enabled* hub is supplied — with
-``enabled=False`` (or no hub at all) the hot path contains no telemetry
-branches, no ``perf_counter`` calls, and no event construction.
+chunked-admission paths).  It is also **zero-overhead when off**: with
+``engine.annotate`` False and no enabled hub, every span site enters the
+shared no-op context — no ``perf_counter`` call, no string formatting, no
+event construction.
 
-Three instruments, one object:
+Four instruments:
 
-* **Phase spans** — every iteration of the scheduler emits structured spans
-  (``admit`` / ``prefill`` / ``chunk_continue`` / ``decode_verify`` /
-  ``commit`` / ``preempt`` / ``retire``) with the seconds charged to each
-  phase, buffered in memory and optionally streamed as a JSONL event log
-  (``jsonl_path=``).  On the device side,
-  :class:`~repro.core.spec_decode.SpecDecodeEngine` wraps each jit dispatch
-  (step, B=1 prefill/chunk forwards, inject/retire scatters) in a
-  ``jax.profiler.TraceAnnotation`` scope when ``engine.annotate`` is set, so
-  a profiler trace (``profile_dir=``) attributes device time per phase.
+* **Spans** — :func:`span` is the one scope helper.  It opens a
+  ``jax.profiler.TraceAnnotation`` named ``repro/<phase>`` when annotation
+  is on (``engine.annotate``), so the span sits on the profiler's host
+  timeline beside the device planes, and, with a hub attached, records the
+  span stamped with ``time.perf_counter()`` at entry and exit (``t0``,
+  ``t1``) plus its attributes.  Groups, one prefix each: ``sched.*`` (the
+  scheduler's host code and its calls into the backend), ``step.*`` (the
+  step call: ``step.prep`` / ``step.key`` / ``step.wait`` /
+  ``step.commit`` / ``step.done``), ``prefill.*`` / ``chunk.*`` /
+  ``attach.*`` (admission host prep and fences), ``retire.out`` /
+  ``preempt.out`` (output reads), and the engine's jit dispatches
+  (``step[B=..,s=..]``, ``prefill[B=..,P=..]``, ``chunk[CB=..]``, ...).
+  Records are buffered in memory and optionally streamed as a JSONL event
+  log (``jsonl_path=``).
+
+* **The compile log** — :func:`compile_log` registers one
+  ``jax.monitoring`` duration listener on first use and keeps a bounded,
+  process-wide log of ``(perf_counter at the end, seconds, event,
+  fun_name)`` for every trace, lowering, backend compile and persistent-
+  cache read.  The engine names each jit after its registry entry and key,
+  so ``fun_name`` says which program compiled.  Nothing runs while nothing
+  compiles.
 
 * **The (s, batch) acceptance observatory** — per executed decode step the
   accepted-draft counts accumulate into histograms keyed by (chosen s, live
@@ -32,9 +46,9 @@ Three instruments, one object:
 
 * **Pool and scheduler gauges** — per-iteration snapshots of slot occupancy
   vs parked-PREFILLING count, backlog depth, block-pool free/used depth and
-  free-list fragmentation, plus monotone counters for every span phase.
-  :meth:`prometheus_text` renders a Prometheus-style text exposition;
-  :meth:`dashboard` renders a console summary (printed every
+  free-list fragmentation, plus monotone counters for every span phase and
+  for compiles.  :meth:`prometheus_text` renders a Prometheus-style text
+  exposition; :meth:`dashboard` renders a console summary (printed every
   ``dashboard_every`` iterations when set).
 
 The standing regression surface over these metrics is
@@ -42,15 +56,126 @@ The standing regression surface over these metrics is
 """
 from __future__ import annotations
 
+import collections
 import json
 import sys
-from typing import Callable, Dict, List, Optional, Tuple
+import time
+from typing import Callable, Deque, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
-# the span taxonomy; scheduler hooks only ever emit these phases
-PHASES = ("admit", "prefill", "chunk_continue", "decode_verify", "commit",
-          "preempt", "retire")
+# the scheduler's span taxonomy (ContinuousScheduler.run); the backend and
+# the engine add the step / admission / retirement groups and their jit
+# dispatch scopes (module docstring)
+PHASES = ("sched.admit", "sched.prefill", "sched.chunk_continue",
+          "sched.choose", "sched.preempt", "sched.decode_verify",
+          "sched.commit", "sched.retire")
+PREFIX = "repro/"              # every span's name on the profiler timeline
+
+
+class _NullSpan:
+    """The shared no-op span: what :func:`span` returns with annotation off
+    and no hub, so a span site constructs nothing."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **attrs) -> None:
+        pass
+
+
+NULLSPAN = _NullSpan()
+
+
+class Span:
+    """One named phase (see :func:`span`)."""
+    __slots__ = ("phase", "hub", "attrs", "t0", "_ann")
+
+    def __init__(self, phase: str, hub, annotate: bool, attrs: dict):
+        self.phase = phase
+        self.hub = hub
+        self.attrs = attrs
+        self._ann = (jax.profiler.TraceAnnotation(PREFIX + phase)
+                     if annotate else None)
+
+    def set(self, **attrs) -> None:
+        """Attributes known only once the phase's work is done."""
+        self.attrs.update(attrs)
+
+    def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self.hub is not None:
+            self.hub.record(self.phase, self.t0, t1, self.attrs)
+        return False
+
+
+def span(phase: str, hub=None, annotate: bool = False, **attrs):
+    """The scope of one phase: a ``TraceAnnotation`` ``repro/<phase>`` when
+    ``annotate``, and with an enabled ``hub`` a record of the phase with
+    ``t0``/``t1`` from ``time.perf_counter()`` (the clock of the backend's
+    fences) and ``attrs``.  Neither: the shared :data:`NULLSPAN`."""
+    if hub is not None and not hub.enabled:
+        hub = None
+    if hub is None and not annotate:
+        return NULLSPAN
+    return Span(phase, hub, annotate, attrs)
+
+
+# ---------------------------------------------------------------------------
+# the compile log
+
+COMPILE_EVENTS = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+                  "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+                  "/jax/core/compile/backend_compile_duration": "compile",
+                  "/jax/compilation_cache/cache_retrieval_time_sec":
+                      "cache_read"}
+COMPILE_LOG_LEN = 8192
+_compile_log: Deque[Tuple[float, float, str, str]] = collections.deque(
+    maxlen=COMPILE_LOG_LEN)
+_compile_total = 0             # backend compiles (cache reads included)
+_listening = False
+
+
+def _on_duration(event: str, duration: float, **kwargs) -> None:
+    global _compile_total
+    kind = COMPILE_EVENTS.get(event)
+    if kind is None:
+        return
+    _compile_log.append((time.perf_counter(), float(duration), kind,
+                         str(kwargs.get("fun_name", ""))))
+    if kind == "compile":
+        _compile_total += 1
+
+
+def compile_log() -> Deque[Tuple[float, float, str, str]]:
+    """The process-wide compile log: ``(perf_counter at the end, seconds,
+    event, fun_name)`` per trace / lower / compile (a backend compile, which
+    also covers a persistent-cache read) / cache_read, the newest
+    ``COMPILE_LOG_LEN`` kept.  The first call registers the listener."""
+    global _listening
+    if not _listening:
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _listening = True
+    return _compile_log
+
+
+def compiles_total() -> int:
+    """Backend compiles since the listener was registered."""
+    compile_log()
+    return _compile_total
 
 
 class Telemetry:
@@ -60,11 +185,13 @@ class Telemetry:
     scheduler goes further and never calls them at all in that case, so a
     disabled hub costs exactly nothing on the hot path.
 
-    ``profile_dir`` arms :meth:`start`/:meth:`stop` to wrap the serving run
-    in a ``jax.profiler`` trace (and implies ``annotate_device=True`` so the
-    trace carries per-phase scopes).  ``jsonl_path`` streams every event as
-    one JSON line at emit time; the in-memory ``events`` buffer always holds
-    the same records (see :meth:`write_jsonl`).
+    ``annotate_device`` makes ``serve_continuous_live`` turn the engine's
+    ``annotate`` switch on for the run, so every span also sits on the
+    profiler timeline.  ``profile_dir`` arms :meth:`start`/:meth:`stop` to
+    wrap the serving run in a ``jax.profiler`` trace (and implies
+    ``annotate_device=True``).  ``jsonl_path`` streams every event as one
+    JSON line at emit time; the in-memory ``events`` buffer always holds the
+    same records.
     """
 
     def __init__(self, enabled: bool = True,
@@ -90,6 +217,7 @@ class Telemetry:
         self.expected_acceptance: Optional[Callable[[int], float]] = None
         self._jsonl = open(jsonl_path, "w") if jsonl_path else None
         self._profiling = False
+        self._compiles0 = compiles_total() if self.enabled else 0
 
     # ------------------------------------------------------------------
     # recording hooks (called by the scheduler only when enabled)
@@ -99,16 +227,22 @@ class Telemetry:
         if self._jsonl is not None:
             self._jsonl.write(json.dumps(ev, default=float) + "\n")
 
-    def span(self, phase: str, iteration: int, dt: float, **attrs) -> None:
-        """Record one completed phase span: ``dt`` seconds charged to
-        ``phase`` during scheduler iteration ``iteration``."""
+    def record(self, phase: str, t0: float, t1: float, attrs: dict) -> None:
+        """Record one completed span (what :class:`Span` calls on exit):
+        ``phase`` ran from ``t0`` to ``t1`` (``time.perf_counter()``)."""
         if not self.enabled:
             return
         self.counters[phase] = self.counters.get(phase, 0) + 1
-        if phase == "commit":
+        if phase == "sched.commit":
             self.tokens_committed += int(attrs.get("tokens", 0))
-        self._emit({"ev": "span", "phase": phase, "iter": int(iteration),
-                    "dt": float(dt), **attrs})
+        self._emit({"ev": "span", "phase": phase, "t0": t0, "t1": t1,
+                    **attrs})
+
+    @property
+    def compiles(self) -> int:
+        """Backend compiles (persistent-cache reads included) in this
+        process since the hub was built."""
+        return compiles_total() - self._compiles0 if self.enabled else 0
 
     def observe_step(self, *, s: int, batch: int, accepted,
                      duration: float) -> None:
@@ -203,6 +337,8 @@ class Telemetry:
                    f"{self.tokens_committed}")
         out.append("# TYPE repro_serving_iterations_total counter")
         out.append(f"repro_serving_iterations_total {self.iterations}")
+        out.append("# TYPE repro_serving_compiles_total counter")
+        out.append(f"repro_serving_compiles_total {self.compiles}")
         for name in sorted(self.gauges):
             out.append(f"# TYPE repro_serving_{name} gauge")
             out.append(f"repro_serving_{name} {self.gauges[name]}")
@@ -249,7 +385,7 @@ class Telemetry:
                 f" · grid occupancy {g.get('grid_occupancy', 0.0):.2f}")
         cnt = " ".join(f"{k}={v}" for k, v in sorted(self.counters.items()))
         lines.append(f" counters: {cnt or '(none)'} · tokens "
-                     f"{self.tokens_committed}")
+                     f"{self.tokens_committed} · compiles {self.compiles}")
         acc = sorted(self.acceptance_table(), key=lambda r: -r["draws"])[:3]
         for r in acc:
             drift = ("" if r["drift"] is None
@@ -264,6 +400,7 @@ class Telemetry:
         return {
             "iterations": self.iterations,
             "tokens_committed": self.tokens_committed,
+            "compiles": self.compiles,
             "counters": dict(self.counters),
             "gauges": dict(self.gauges),
             "peaks": dict(self.peaks),
@@ -274,23 +411,21 @@ class Telemetry:
     # ------------------------------------------------------------------
     # persistence / profiler lifecycle
 
-    def write_jsonl(self, path: str) -> None:
-        """Dump the buffered event log to ``path`` (one JSON per line)."""
-        with open(path, "w") as f:
-            for ev in self.events:
-                f.write(json.dumps(ev, default=float) + "\n")
-
     def start(self) -> None:
         """Begin the jax profiler trace when ``profile_dir`` is set (no-op
-        otherwise); the serving entry points call this around the run."""
+        otherwise); the serving entry points call this around the run.  The
+        Python tracer stays off (it slows every Python call several times
+        over); the host tracer level keeps the ``TraceAnnotation`` spans."""
         if self.enabled and self.profile_dir and not self._profiling:
-            import jax
-            jax.profiler.start_trace(self.profile_dir)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            opts.host_tracer_level = 2
+            jax.profiler.start_trace(self.profile_dir,
+                                     profiler_options=opts)
             self._profiling = True
 
     def stop(self) -> None:
         if self._profiling:
-            import jax
             jax.profiler.stop_trace()
             self._profiling = False
         if self._jsonl is not None:

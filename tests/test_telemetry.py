@@ -7,6 +7,7 @@ the contiguous, paged-under-preemption, and chunked-admission paths."""
 import dataclasses
 import io
 import json
+import time
 
 import jax
 import numpy as np
@@ -22,7 +23,7 @@ from repro.serving.scheduler import (ContinuousEngineBackend,
                                      SimStepBackend, serve_continuous_live)
 from repro.serving.server import serve_continuous
 from repro.serving.slots import BlockPool
-from repro.serving.telemetry import PHASES, Telemetry
+from repro.serving.telemetry import NULLSPAN, PHASES, Telemetry, span
 from repro.serving.traffic import TrafficPhase, make_requests, uniform_traffic
 
 CACHE_LEN = 96
@@ -68,8 +69,10 @@ def test_disabled_telemetry_is_inert():
     sched.run(uniform_traffic(20, 0.01, 1.0, 100, seed=4, max_new=8))
     assert tel.events == [] and tel.counters == {}
     assert tel.iterations == 0 and tel.acceptance_table() == []
-    # direct calls short-circuit too while disabled
-    tel.span("prefill", 0, 0.1, rid=1)
+    # direct calls short-circuit too while disabled, and a disabled hub
+    # gets no span
+    tel.record("sched.prefill", 0.0, 0.1, {"rid": 1})
+    assert span("sched.prefill", tel) is NULLSPAN
     tel.observe_step(s=2, batch=2, accepted=[1, 2], duration=0.1)
     tel.iteration(0, 0.0, occupancy=1)
     assert tel.events == [] and tel.counters == {} and tel.gauges == {}
@@ -124,23 +127,32 @@ def test_span_taxonomy_counters_and_jsonl(tmp_path):
     assert lines, "no events streamed"
     spans = [e for e in lines if e["ev"] == "span"]
     assert {e["phase"] for e in spans} <= set(PHASES)
+    # every span is stamped on the perf_counter clock, in order
+    assert all(e["t0"] <= e["t1"] for e in spans)
     # counters must reconcile with the StepTrace ground truth
-    assert tel.counters["chunk_continue"] == sum(
+    assert tel.counters["sched.chunk_continue"] == sum(
         len(t.chunked) for t in trace)
-    assert tel.counters["preempt"] == sum(len(t.preempted) for t in trace)
-    assert tel.counters["preempt"] > 0, "geometry lost its preemption bite"
-    assert tel.counters["decode_verify"] == sum(
+    assert tel.counters["sched.preempt"] == sum(
+        len(t.preempted) for t in trace)
+    assert tel.counters["sched.preempt"] > 0, \
+        "geometry lost its preemption bite"
+    assert tel.counters["sched.decode_verify"] == sum(
         1 for t in trace if t.occupancy > 0)
-    assert tel.counters["retire"] == len(res.requests)
-    assert tel.counters["admit"] == sum(1 for t in trace if t.admitted)
-    assert tel.counters.get("prefill", 0) == sum(
+    assert tel.counters["sched.commit"] == tel.counters["sched.decode_verify"]
+    assert tel.counters["sched.retire"] == len(res.requests)
+    # the admission pass runs every iteration; those that admitted say so
+    assert tel.counters["sched.admit"] >= len(trace)
+    assert sum(1 for e in spans if e["phase"] == "sched.admit"
+               and e.get("rids")) == sum(1 for t in trace if t.admitted)
+    assert tel.counters.get("sched.prefill", 0) == sum(
         1 for t in trace for dt in t.prefill_s if dt >= 0)
     # the in-memory buffer and the streamed file are the same log
     assert len(tel.events) == len(lines)
     # commit spans accumulate exactly the tokens the requests ended up with
     assert tel.tokens_committed == sum(r.n_generated for r in res.requests)
     # per-span dt totals match the chunk seconds the trace recorded
-    chunk_dt = sum(e["dt"] for e in spans if e["phase"] == "chunk_continue")
+    chunk_dt = sum(e["dt"] for e in spans
+                   if e["phase"] == "sched.chunk_continue")
     assert chunk_dt == pytest.approx(sum(sum(t.chunk_s) for t in trace))
 
 
@@ -180,7 +192,8 @@ def test_gauges_prometheus_and_dashboard():
     assert tel.peaks["used_blocks"] > 0
     text = tel.prometheus_text()
     assert "repro_serving_occupancy 0" in text
-    assert 'repro_serving_spans_total{phase="decode_verify"}' in text
+    assert 'repro_serving_spans_total{phase="sched.decode_verify"}' in text
+    assert "repro_serving_compiles_total " in text
     assert "repro_serving_acceptance_observed{" in text
     assert "repro_serving_peak_occupancy" in text
     dash = tel.dashboard()
@@ -263,8 +276,10 @@ def test_live_token_and_trace_identity_with_telemetry(engine, case,
                                     telemetry=tel)
         return res, be
 
-    tel = Telemetry(jsonl_path=str(tmp_path / f"{case}.jsonl"))
+    tel = Telemetry(jsonl_path=str(tmp_path / f"{case}.jsonl"),
+                    annotate_device=True)
     (r0, b0), (r1, b1) = go(None), go(tel)
+    assert eng.annotate is False and eng.telemetry is None
     tel.close()
     for f in ("admitted", "occupancy", "committed", "preempted",
               "done_rids", "chunked"):
@@ -276,12 +291,18 @@ def test_live_token_and_trace_identity_with_telemetry(engine, case,
                                       err_msg=f"{case} rid {rid}")
     # each case exercised the machinery it claims to cover
     if case == "paged_preempt":
-        assert tel.counters["preempt"] > 0
+        assert tel.counters["sched.preempt"] > 0
+        assert tel.counters["preempt.out"] == tel.counters["sched.preempt"]
         assert sum(len(t.preempted) for t in r1.trace) > 0
     if case == "chunked":
-        assert tel.counters["chunk_continue"] > 0
+        assert tel.counters["sched.chunk_continue"] > 0
+        assert tel.counters["chunk.fence"] > 0
         assert sum(len(t.chunked) for t in r1.trace) > 0
-    assert tel.counters["retire"] == len(r1.requests)
+    assert tel.counters["sched.retire"] == len(r1.requests)
+    # the engine's and the backend's spans reached the hub too
+    assert tel.counters["step.wait"] == tel.counters["sched.decode_verify"]
+    assert tel.counters["step.done"] == tel.counters["sched.decode_verify"]
+    assert tel.counters["retire.out"] == len(r1.requests)
     assert tel.tokens_committed == sum(r.n_generated for r in r1.requests)
 
 
@@ -298,3 +319,116 @@ def test_device_annotation_scopes_run_and_reset(engine):
     assert all(r.finish is not None for r in res.requests)
     assert tel.iterations == len(res.trace)
     assert eng.annotate is False          # restored after the run
+
+
+def test_spans_off_construct_nothing(engine, monkeypatch):
+    """With the span switch off and no enabled hub, every span site takes
+    the shared no-op path: a serve completes with TraceAnnotation and Span
+    unbuildable, and the hub stays empty."""
+    import repro.serving.telemetry as T
+    eng, tp, dp, tcfg = engine
+
+    def refuse(*a, **k):
+        raise AssertionError("a span was built with spans off")
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    monkeypatch.setattr(T, "Span", refuse)
+    tel = Telemetry(enabled=False)
+    be = ContinuousEngineBackend(eng, tp, dp, capacity=4,
+                                 cache_len=CACHE_LEN, warm_s=(2, 3, 4),
+                                 block_size=BLOCK, num_blocks=40)
+    res = serve_continuous_live(_live_trace(tcfg, long_every=3), eng, tp, dp,
+                                _ctrl(), backend=be, telemetry=tel,
+                                policy=PrefillBudgetAdmit(token_budget=16,
+                                                          chunk=8))
+    assert all(r.finish is not None for r in res.requests)
+    assert sum(len(t.chunked) for t in res.trace) > 0
+    assert tel.events == [] and tel.counters == {}
+    assert eng.annotate is False and eng.telemetry is None
+
+
+def test_compile_log_names_the_jit_of_a_shape_change(engine):
+    """A prompt in a new bucket, admitted after the serve is under way,
+    compiles its prefill mid-serve; the program's compile log names the jit
+    by registry entry and key, and the hub counts it."""
+    from repro.serving.telemetry import compile_log
+    eng, tp, dp, tcfg = engine
+    reqs = _live_trace(tcfg, n=5, budget=(6, 9))
+    rng = np.random.default_rng(5)
+    for r in reqs[:4]:
+        r.tokens = rng.integers(0, tcfg.vocab_size, (10,)).astype(np.int32)
+        r.prompt_len = 10
+    # bucket 96 (the whole cache), served last
+    reqs[4].tokens = rng.integers(0, tcfg.vocab_size, (70,)).astype(np.int32)
+    reqs[4].prompt_len = 70
+    reqs[4].arrival = 1e-9
+    tel = Telemetry()
+    t_start = time.perf_counter()
+    # capacity 3 is a pool shape no other test compiles
+    serve_continuous_live(reqs, eng, tp, dp, _ctrl(), capacity=3,
+                          cache_len=CACHE_LEN, telemetry=tel)
+    log = [e for e in compile_log() if e[0] >= t_start]
+    names = {name for _, _, event, name in log if event == "compile"}
+    assert any("step__3_" in n for n in names), names
+    assert any(n.endswith(f"prefill__1_96_{CACHE_LEN})") for n in names), \
+        names
+    first_step = min(t for t, _, ev, n in log
+                     if ev == "compile" and "step__3_" in n)
+    late = [t for t, _, ev, n in log
+            if ev == "compile" and n.endswith(f"prefill__1_96_{CACHE_LEN})")]
+    assert min(late) > first_step       # compiled after serving began
+    assert tel.compiles == len([e for e in log if e[2] == "compile"])
+    assert f"repro_serving_compiles_total {tel.compiles}" in \
+        tel.prometheus_text()
+
+
+def test_profile_start_keeps_the_python_tracer_off(monkeypatch, tmp_path):
+    seen = {}
+
+    def start(log_dir, profiler_options=None, **_):
+        seen["opts"] = profiler_options
+    monkeypatch.setattr(jax.profiler, "start_trace", start)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    tel = Telemetry(profile_dir=str(tmp_path))
+    assert tel.annotate_device
+    tel.start()
+    tel.close()
+    assert seen["opts"].python_tracer_level == 0
+    assert seen["opts"].host_tracer_level == 2
+
+
+def test_every_allowed_host_sync_sits_in_a_named_scope():
+    """Each deliberate sync of the host-sync hot zones (the lines carrying
+    ``# lint: allow-host-sync``) runs inside a span scope, so a profiler
+    trace names the wait."""
+    import ast
+    import os
+    from tools.lint import astutil, pragmas
+    from tools.lint.rules.host_sync import HOT_QUALNAMES
+    src = os.path.join(os.path.dirname(os.path.dirname(__file__)), "src",
+                       "repro")
+    checked = 0
+    for parts, quals in HOT_QUALNAMES.items():
+        path = os.path.join(src, *parts)
+        source = open(path).read()
+        tree = ast.parse(source)
+        astutil.attach_parents(tree)
+        lines = {p.target_line for p in pragmas.collect(path, source)
+                 if p.rule == "host-sync"}
+        for node in ast.walk(tree):
+            if not (isinstance(node, ast.Call) and node.lineno in lines):
+                continue
+            fns = [astutil.qualname(f)
+                   for f in astutil.enclosing_functions(node)]
+            if not any(q == h or q.startswith(h + ".")
+                       for q in fns for h in quals):
+                continue
+            scoped = any(
+                isinstance(p, ast.With) and any(
+                    isinstance(it.context_expr, ast.Call)
+                    and getattr(it.context_expr.func, "attr",
+                                getattr(it.context_expr.func, "id", ""))
+                    in ("scope", "span") for it in p.items)
+                for p in astutil.parents(node))
+            assert scoped, f"{'/'.join(parts)}:{node.lineno}"
+            checked += 1
+    assert checked >= 10

@@ -162,3 +162,32 @@ def test_spec_verify_kernel_partitions_over_a_v5e_mesh(topo):
     text = _compiled_text(fn, *args)
     assert "tpu_custom_call" in text
     assert "all-gather" not in text      # each chip reads its own slots
+
+
+@pytest.mark.parametrize("kernel", ["ragged_paged_verify_attn",
+                                    "paged_verify_attn", "spec_verify_attn"])
+def test_serving_kernels_are_named_in_the_compiled_program(one_chip, kernel):
+    """Each serving kernel's custom call carries its own name in the
+    compiled program, so a device trace names it; the ragged one keeps
+    the scalar-prefetched s32 plan as its first operand."""
+    import re
+    a = _paged_args(one_chip)
+    if kernel == "ragged_paged_verify_attn":
+        text = _compiled_text(
+            lambda q, k, v, qp, pos, bt, cu: ragged_paged_verify_attn_pallas(
+                q, k, v, qp, pos, bt, cu),
+            a["q"], a["k"], a["v"], a["q_pos"], a["pos"], a["bt"], a["cu"])
+    elif kernel == "paged_verify_attn":
+        text = _compiled_text(
+            lambda q, k, v, qp, pos, bt: paged_verify_attn_pallas(
+                q, k, v, qp, pos, bt),
+            a["q"], a["k"], a["v"], a["q_pos"], a["pos"], a["bt"])
+    else:
+        s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                    sharding=one_chip)
+        N, L = B * KVH, 1024
+        text = _compiled_text(spec_verify_attn_pallas, s((N, 8, HD), BF),
+                              s((N, L, HD), BF), s((N, L, HD), BF),
+                              s((N, 8), I32), s((N, L), I32))
+    calls = re.findall(rf"%{kernel}(?:\.\d+)? = .*custom-call\(", text)
+    assert calls, text[:2000]
