@@ -94,12 +94,13 @@ CHECK_SHAPE = (4, 3, 8)                  # (batch, s, max_blocks) for --check
 
 def build_case(B: int, s: int, MAXB: int, bs: int = BLOCK_SIZE,
                seed: int = 0):
-    """A ragged paged pool + verify-step inputs for one grid point."""
+    """A ragged paged pool + verify-step inputs for one grid point.  The
+    pool holds one layer, ``[1, NB, bs, KVH, HD]``, read at layer 0."""
     rng = np.random.default_rng(seed)
     T = s + 1
     NB = B * MAXB + 4                    # slack blocks (unowned => garbage)
-    k = jnp.asarray(rng.normal(size=(NB, bs, KVH, HD)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(NB, bs, KVH, HD)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(1, NB, bs, KVH, HD)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(1, NB, bs, KVH, HD)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(B, T, H, HD)), jnp.float32)
     bt = np.full((B, MAXB), -1, np.int32)
     pos = np.full((NB, bs), -1, np.int32)
@@ -152,10 +153,10 @@ def temp_bytes(fn, args) -> Optional[int]:
         return None
 
 
-def _profiled_ragged(q, k, v, qp, pos, bt, cu, *, config, profile):
+def _profiled_ragged(q, k, v, qp, pos, bt, layer, cu, *, config, profile):
     from repro.kernels.paged_verify_attn import ragged_paged_verify_attn_pallas
     return ragged_paged_verify_attn_pallas(
-        q, k, v, qp, pos, bt, cu,
+        q, k, v, qp, pos, bt, layer, cu,
         num_buffers=config.num_buffers,
         vmem_limit_bytes=config.vmem_limit_bytes,
         profile=profile, interpret=jax.default_backend() != "tpu")
@@ -167,7 +168,7 @@ def _ragged_fn(config: RaggedConfig, profile: Optional[str] = None):
     measures exactly the knobs it thinks it measures)."""
     if profile is None:
         return jax.jit(lambda *a: paged_verify_attn(
-            *a[:6], use_pallas=True, cu_blocks=a[6], config=config))
+            *a[:7], use_pallas=True, cu_blocks=a[7], config=config))
     return jax.jit(lambda *a: _profiled_ragged(*a, config=config,
                                                profile=profile))
 
@@ -180,7 +181,7 @@ def bench_case(B: int, s: int, MAXB: int, bs: int = BLOCK_SIZE,
     gpal = jax.jit(lambda *a: gather_verify_attn(*a, use_pallas=True,
                                                  block_k=bs))
     gref = jax.jit(lambda *a: gather_verify_attn(*a, use_pallas=False))
-    args = (q, k, v, qp, pos, bt)
+    args = (q, k, v, qp, pos, bt, 0)
     tables = np.asarray(bt)
     cu = jnp.asarray(host_cu_blocks(tables))
     config = config or RaggedConfig()
@@ -243,7 +244,7 @@ def autotune_case(B: int, s: int, MAXB: int, bs: int = BLOCK_SIZE) -> Dict:
     winner is what ``lookup_config`` hands the serving dispatch."""
     q, k, v, qp, pos, bt = build_case(B, s, MAXB, bs)
     cu = jnp.asarray(host_cu_blocks(np.asarray(bt)))
-    rargs = (q, k, v, qp, pos, bt, cu)
+    rargs = (q, k, v, qp, pos, bt, 0, cu)
     vmem_limits = (SEARCH_VMEM_LIMITS if jax.default_backend() == "tpu"
                    else (None,))   # interpret mode ignores the VMEM budget
     trials = []

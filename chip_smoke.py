@@ -196,7 +196,8 @@ def kernel_check(*, B=8, T=4, H=16, KVH=8, hd=128, bs=BLOCK_SIZE,
 
     c = _paged_case(B, T, H, KVH, hd, bs, max_blocks, seed)
     bf = jnp.bfloat16
-    q, k, v = (jnp.asarray(c[n], bf) for n in ("q", "k", "v"))
+    q = jnp.asarray(c["q"], bf)
+    k, v = (jnp.asarray(c[n], bf)[None] for n in ("k", "v"))   # one layer
     q_pos, pos, bt = (jnp.asarray(c[n]) for n in ("q_pos", "pos", "bt"))
     cu = jnp.asarray(host_cu_blocks(c["bt"]))
     # the reference sees the kernel's bf16 inputs, widened to float32
@@ -209,16 +210,16 @@ def kernel_check(*, B=8, T=4, H=16, KVH=8, hd=128, bs=BLOCK_SIZE,
     runs = {
         f"ragged(num_buffers={served.num_buffers})":
             lambda: ragged_paged_verify_attn_pallas(
-                q, k, v, q_pos, pos, bt, cu,
+                q, k, v, q_pos, pos, bt, 0, cu,
                 num_buffers=served.num_buffers,
                 vmem_limit_bytes=served.vmem_limit_bytes,
                 interpret=interpret),
         "ragged(num_buffers=2)":
             lambda: ragged_paged_verify_attn_pallas(
-                q, k, v, q_pos, pos, bt, cu, num_buffers=2,
+                q, k, v, q_pos, pos, bt, 0, cu, num_buffers=2,
                 interpret=interpret),
         "dense": lambda: paged_verify_attn_pallas(
-            q, k, v, q_pos, pos, bt, interpret=interpret),
+            q, k, v, q_pos, pos, bt, 0, interpret=interpret),
         "contiguous": lambda: ops.spec_verify_attn(
             q, jnp.asarray(c["kl"], bf), jnp.asarray(c["vl"], bf), q_pos,
             jnp.asarray(c["kp"]), use_pallas=True),

@@ -3,9 +3,15 @@ TPU, materialized gather as the reference / CPU fallback.
 
 The paged pool stores KV rows in fixed-size blocks shared by every slot:
 
-    k_pool / v_pool : [num_blocks, block_size, KVH, hd]
+    k_pool / v_pool : [n_layers, num_blocks, block_size, KVH, hd]
     pos             : [num_blocks, block_size]   absolute position, -1 unwritten
     block_tables    : [B, max_blocks]            physical block ids, -1 unused
+
+Every entry point takes the whole pool and a ``layer`` index: the model's
+layer scan carries the pool and writes it in place, so no per-layer slice
+of it ever exists, and the kernels read ``(layer, block)`` tiles directly.
+A caller holding one layer's ``[NB, ...]`` pool passes ``k[None]`` and
+layer 0.
 
 Two execution paths with identical masking semantics:
 
@@ -33,7 +39,7 @@ additionally removes the gather's transient double-buffering of the hot
 verify step — the largest single-lever perf win on the serving path.
 
 int8 pools (kv_quant) pass per-(row, kv-head) ``k_scale``/``v_scale``
-``[NB, bs, KVH]``; both paths dequantize with them (the fused kernel in
+``[nL, NB, bs, KVH]``; both paths dequantize with them (the fused kernel in
 VMEM after a 1 B/elem stream, the gather path before the shared wrapper).
 """
 from __future__ import annotations
@@ -50,13 +56,17 @@ from repro.kernels.tuning import RaggedConfig, lookup_config
 
 
 def gather_kv_blocks(k: jax.Array, v: jax.Array, block_tables: jax.Array,
+                     layer: int | jax.Array,
                      ) -> Tuple[jax.Array, jax.Array]:
-    """Gather per-slot logical KV views from the shared block pool.
+    """Gather per-slot logical KV views of one layer from the shared block
+    pool.
 
-    k/v: [NB, bs, KVH, hd]; block_tables: [B, MAXB] (-1 = unallocated).
-    Returns (k_slot, v_slot) of shape [B, MAXB * bs, KVH, hd].  Rows behind
-    -1 table entries contain arbitrary pool data — callers must mask them
-    via :func:`gather_key_positions` (which reports their position as -1).
+    k/v: [nL, NB, bs, KVH, hd]; block_tables: [B, MAXB] (-1 = unallocated);
+    ``layer``: int or int32 scalar.  One gather of ``(layer, block)`` pairs,
+    with no slice of the layer first.  Returns (k_slot, v_slot) of shape
+    [B, MAXB * bs, KVH, hd].  Rows behind -1 table entries contain arbitrary
+    pool data — callers must mask them via :func:`gather_key_positions`
+    (which reports their position as -1).
 
     Fast path: with a one-block-per-slot table (MAXB == 1 — short-prompt
     traces sized to a single block) the gather+reshape collapses to a
@@ -64,12 +74,12 @@ def gather_kv_blocks(k: jax.Array, v: jax.Array, block_tables: jax.Array,
     microbenchmark's smallest shapes.
     """
     B, MAXB = block_tables.shape
-    bs = k.shape[1]
+    bs = k.shape[2]
     safe = jnp.where(block_tables < 0, 0, block_tables)
     if MAXB == 1:
-        return k[safe[:, 0]], v[safe[:, 0]]
-    kg = k[safe].reshape(B, MAXB * bs, *k.shape[2:])
-    vg = v[safe].reshape(B, MAXB * bs, *v.shape[2:])
+        return k[layer, safe[:, 0]], v[layer, safe[:, 0]]
+    kg = k[layer, safe].reshape(B, MAXB * bs, *k.shape[3:])
+    vg = v[layer, safe].reshape(B, MAXB * bs, *v.shape[3:])
     return kg, vg
 
 
@@ -85,23 +95,27 @@ def gather_key_positions(pos: jax.Array, block_tables: jax.Array) -> jax.Array:
     return kp.reshape(B, MAXB * bs)
 
 
-def gather_scales(scale: jax.Array, block_tables: jax.Array) -> jax.Array:
-    """Gather int8 dequant scales [NB, bs, KVH] -> per-slot [B, MAXB*bs, KVH].
+def gather_scales(scale: jax.Array, block_tables: jax.Array,
+                  layer: int | jax.Array) -> jax.Array:
+    """Gather one layer's int8 dequant scales [nL, NB, bs, KVH] -> per-slot
+    [B, MAXB*bs, KVH], as ``(layer, block)`` pairs like
+    :func:`gather_kv_blocks`.
 
     Rows behind -1 table entries carry arbitrary pool scales; they are
     harmless because their key positions gather as -1 (never attendable).
     """
     B, MAXB = block_tables.shape
-    bs = scale.shape[1]
+    bs = scale.shape[2]
     safe = jnp.where(block_tables < 0, 0, block_tables)
     if MAXB == 1:
-        return scale[safe[:, 0]]
-    return scale[safe].reshape(B, MAXB * bs, scale.shape[2])
+        return scale[layer, safe[:, 0]]
+    return scale[layer, safe].reshape(B, MAXB * bs, scale.shape[3])
 
 
 def gather_verify_attn(q: jax.Array, k: jax.Array, v: jax.Array,
                        q_pos: jax.Array, pos: jax.Array,
                        block_tables: jax.Array,
+                       layer: int | jax.Array,
                        window: Optional[int] = None, prefix_len: int = 0,
                        scale: Optional[float] = None,
                        k_scale: Optional[jax.Array] = None,
@@ -116,12 +130,12 @@ def gather_verify_attn(q: jax.Array, k: jax.Array, v: jax.Array,
     ``use_pallas`` is forwarded to the shared wrapper (the microbenchmark
     times gather+Pallas-verify against the fused kernel with it).
     """
-    kg, vg = gather_kv_blocks(k, v, block_tables)
+    kg, vg = gather_kv_blocks(k, v, block_tables, layer)
     kpos = gather_key_positions(pos, block_tables)
     ks = vs = None
     if k_scale is not None:
-        ks = gather_scales(k_scale, block_tables)
-        vs = gather_scales(v_scale, block_tables)
+        ks = gather_scales(k_scale, block_tables, layer)
+        vs = gather_scales(v_scale, block_tables, layer)
     return spec_verify_attn(q, kg, vg, q_pos, kpos, window=window,
                             prefix_len=prefix_len, scale=scale,
                             k_scale=ks, v_scale=vs, use_pallas=use_pallas,
@@ -131,6 +145,7 @@ def gather_verify_attn(q: jax.Array, k: jax.Array, v: jax.Array,
 def paged_verify_attn(q: jax.Array, k: jax.Array, v: jax.Array,
                       q_pos: jax.Array, pos: jax.Array,
                       block_tables: jax.Array,
+                      layer: int | jax.Array,
                       window: Optional[int] = None, prefix_len: int = 0,
                       scale: Optional[float] = None,
                       k_scale: Optional[jax.Array] = None,
@@ -140,9 +155,11 @@ def paged_verify_attn(q: jax.Array, k: jax.Array, v: jax.Array,
                       config: Optional[RaggedConfig] = None) -> jax.Array:
     """Verify-step attention against the paged pool.
 
-    q: [B, T, H, hd]; k/v: [NB, bs, KVH, hd]; q_pos: [B, T];
-    pos: [NB, bs]; block_tables: [B, MAXB].  Optional k_scale/v_scale
-    [NB, bs, KVH] for int8 pools.  Returns [B, T, H, hd].
+    q: [B, T, H, hd]; k/v: [nL, NB, bs, KVH, hd], the whole pool;
+    q_pos: [B, T]; pos: [NB, bs]; block_tables: [B, MAXB]; ``layer``: the
+    layer attended (int or int32 scalar — inside the model's layer scan,
+    the scan index).  Optional k_scale/v_scale [nL, NB, bs, KVH] for int8
+    pools.  Returns [B, T, H, hd].
 
     Dispatch (:func:`~repro.kernels.ops.kernel_mode` policy): a fused
     streaming kernel natively on TPU (or interpreted when forced with
@@ -166,7 +183,7 @@ def paged_verify_attn(q: jax.Array, k: jax.Array, v: jax.Array,
     """
     m = kernel_mode(use_pallas)
     if m == "ref":
-        return gather_verify_attn(q, k, v, q_pos, pos, block_tables,
+        return gather_verify_attn(q, k, v, q_pos, pos, block_tables, layer,
                                   window=window, prefix_len=prefix_len,
                                   scale=scale, k_scale=k_scale,
                                   v_scale=v_scale, use_pallas=None)
@@ -176,14 +193,15 @@ def paged_verify_attn(q: jax.Array, k: jax.Array, v: jax.Array,
             config = lookup_config(q.shape[0], q.shape[1],
                                    block_tables.shape[1])
         return ragged_paged_verify_attn_pallas(
-            q, k, v, q_pos, pos, block_tables, cu_blocks,
+            q, k, v, q_pos, pos, block_tables, layer, cu_blocks,
             window=window, prefix_len=prefix_len, scale=scale,
             k_scale=k_scale, v_scale=v_scale,
             num_buffers=config.num_buffers,
             vmem_limit_bytes=config.vmem_limit_bytes,
             interpret=(m == "interpret"))
     return paged_verify_attn_pallas(q, k, v, q_pos, pos, block_tables,
-                                    window=window, prefix_len=prefix_len,
+                                    layer, window=window,
+                                    prefix_len=prefix_len,
                                     scale=scale, k_scale=k_scale,
                                     v_scale=v_scale,
                                     interpret=(m == "interpret"))

@@ -12,6 +12,14 @@ through the slot's block-table row, prefetched as a scalar
 (``PrefetchScalarGridSpec``) so the index maps can consume it before the
 kernel body runs.
 
+The kernels take the whole ``[nL, NB, bs, KVH, hd]`` pool and the layer
+index, which rides the scalar-prefetch channel as an ``int32[1]`` operand:
+the k/v (and int8 scale) index maps return ``(layer, block, ...)`` with
+the layer dimension squeezed, and the manual-DMA ring copies
+``k_hbm.at[layer, block]``.  The model's layer scan carries the pool and
+writes it in place, so no per-layer slice of it exists anywhere.  A caller
+holding one layer's pool passes ``k[None]`` and layer 0.
+
 Two grid strategies over the same tile math:
 
 * **dense** (:func:`paged_verify_attn_pallas`): grid ``(batch,
@@ -68,7 +76,7 @@ extension (positions ``start .. start+n``) — per-query-row masking plus
 per-row block tables make the kernel agnostic to which is which, and
 ``q_pos = -1`` rows (padding in heterogeneous launches) match nothing.
 
-GQA: the pool keeps its ``[NB, bs, KVH, hd]`` layout (one DMA per owned
+GQA: the pool keeps its ``[nL, NB, bs, KVH, hd]`` layout (one DMA per owned
 block covers every kv head — blocks are owned by exactly one slot, so each
 pool row is read exactly once per step, the HBM floor), and the kernel
 loops the kv heads as an unrolled static loop of 2D MXU dots.  The q block
@@ -174,6 +182,12 @@ def _fold_q(q: jax.Array, q_pos: jax.Array, kvh: int):
     return qf, qpf, GT, unfold
 
 
+def _layer_operand(layer: int | jax.Array) -> jax.Array:
+    """The layer index as the ``int32[1]`` scalar-prefetch operand the
+    index maps (and the DMA ring) address the ``[nL, NB, ...]`` pool with."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
 def _acc_scratch(kvh: int, gt: int, hd: int):
     """Online-softmax VMEM scratch: accumulator, running max, running sum."""
     return [pltpu.VMEM((kvh, gt, hd), jnp.float32),
@@ -185,7 +199,7 @@ def _acc_scratch(kvh: int, gt: int, hd: int):
 # dense grid: (batch, max_blocks_per_slot), @pl.when skipping dead tiles
 
 
-def _fused_kernel(bt_ref, q_ref, k_ref, v_ref, qp_ref, pp_ref, *rest,
+def _fused_kernel(bt_ref, ly_ref, q_ref, k_ref, v_ref, qp_ref, pp_ref, *rest,
                   scale: float, window: Optional[int], prefix_len: int,
                   nb: int, kvh: int, quant: bool):
     if quant:
@@ -223,6 +237,7 @@ def _fused_kernel(bt_ref, q_ref, k_ref, v_ref, qp_ref, pp_ref, *rest,
 def paged_verify_attn_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                              q_pos: jax.Array, pos: jax.Array,
                              block_tables: jax.Array,
+                             layer: int | jax.Array,
                              window: Optional[int] = None,
                              prefix_len: int = 0,
                              scale: Optional[float] = None,
@@ -232,10 +247,12 @@ def paged_verify_attn_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     """Verify-step attention against the paged pool, fused, dense grid.
 
     q: [B, T, H, hd] (tiny T = s+1, or a prefill chunk); k/v:
-    [NB, bs, KVH, hd] pool; q_pos: [B, T]; pos: [NB, bs] (absolute position,
-    -1 unwritten); block_tables: [B, MAXB] (physical block ids, -1 unused).
-    Optional k_scale/v_scale: [NB, bs, KVH] per-(row, kv-head) dequant
-    scales for an int8 pool.  Returns [B, T, H, hd].
+    [nL, NB, bs, KVH, hd], the whole pool of every layer; q_pos: [B, T];
+    pos: [NB, bs] (absolute position, -1 unwritten); block_tables: [B, MAXB]
+    (physical block ids, -1 unused); ``layer``: the layer whose tiles are
+    read (int or int32 scalar).  Optional k_scale/v_scale: [nL, NB, bs, KVH]
+    per-(row, kv-head) dequant scales for an int8 pool.  Returns
+    [B, T, H, hd].
 
     No ``[B, MAXB*bs, ...]`` logical view is ever built: tiles stream from
     the pool through the prefetched block table (module docstring).  The
@@ -243,45 +260,47 @@ def paged_verify_attn_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     cost grid steps; :func:`ragged_paged_verify_attn_pallas` removes them.
     """
     B, T, H, hd = q.shape
-    bs, KVH = k.shape[1], k.shape[2]
+    bs, KVH = k.shape[2], k.shape[3]
     MAXB = block_tables.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     qf, qpf, GT, unfold = _fold_q(q, q_pos, KVH)
 
-    # index maps receive the prefetched block table; dead entries clip to
-    # physical block 0 (valid address, body skips the tile — and repeated
-    # dead entries revisit the same block, so the pipeline elides the DMA)
-    def _kv_map(b, j, bt):
-        return (jnp.maximum(bt[b, j], 0), 0, 0, 0)
+    # index maps receive the prefetched block table and layer; dead entries
+    # clip to physical block 0 (valid address, body skips the tile — and
+    # repeated dead entries revisit the same block, so the pipeline elides
+    # the DMA)
+    def _kv_map(b, j, bt, ly):
+        return (ly[0], jnp.maximum(bt[b, j], 0), 0, 0, 0)
 
-    def _pos_map(b, j, bt):
+    def _pos_map(b, j, bt, ly):
         return (jnp.maximum(bt[b, j], 0), 0, 0)
 
-    def _scale_map(b, j, bt):
-        return (jnp.maximum(bt[b, j], 0), 0, 0)
+    def _scale_map(b, j, bt, ly):
+        return (ly[0], jnp.maximum(bt[b, j], 0), 0, 0)
 
     quant = k_scale is not None
     in_specs = [
-        pl.BlockSpec((1, KVH, GT, hd), lambda b, j, bt: (b, 0, 0, 0)),
-        pl.BlockSpec((1, bs, KVH, hd), _kv_map),
-        pl.BlockSpec((1, bs, KVH, hd), _kv_map),
-        pl.BlockSpec((1, GT, 1), lambda b, j, bt: (b, 0, 0)),
+        pl.BlockSpec((1, KVH, GT, hd), lambda b, j, bt, ly: (b, 0, 0, 0)),
+        pl.BlockSpec((None, 1, bs, KVH, hd), _kv_map),
+        pl.BlockSpec((None, 1, bs, KVH, hd), _kv_map),
+        pl.BlockSpec((1, GT, 1), lambda b, j, bt, ly: (b, 0, 0)),
         pl.BlockSpec((1, 1, bs), _pos_map),
     ]
-    args = [block_tables, qf, k, v, qpf, pos[:, None, :]]
+    args = [block_tables, _layer_operand(layer), qf, k, v, qpf,
+            pos[:, None, :]]
     if quant:
-        in_specs += [pl.BlockSpec((1, bs, KVH), _scale_map),
-                     pl.BlockSpec((1, bs, KVH), _scale_map)]
+        in_specs += [pl.BlockSpec((None, 1, bs, KVH), _scale_map),
+                     pl.BlockSpec((None, 1, bs, KVH), _scale_map)]
         args += [k_scale, v_scale]
     kern = functools.partial(_fused_kernel, scale=scale, window=window,
                              prefix_len=prefix_len, nb=MAXB, kvh=KVH,
                              quant=quant)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, MAXB),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, KVH, GT, hd),
-                               lambda b, j, bt: (b, 0, 0, 0)),
+                               lambda b, j, bt, ly: (b, 0, 0, 0)),
         scratch_shapes=_acc_scratch(KVH, GT, hd),
     )
     o = pl.pallas_call(
@@ -330,8 +349,8 @@ def ragged_plan(block_tables: jax.Array, cu_blocks: jax.Array):
     return ss, sb, pbs
 
 
-def _ragged_kernel(bt_ref, ss_ref, sb_ref, cu_ref, q_ref, k_ref, v_ref,
-                   qp_ref, pp_ref, *rest,
+def _ragged_kernel(bt_ref, ly_ref, ss_ref, sb_ref, cu_ref, q_ref, k_ref,
+                   v_ref, qp_ref, pp_ref, *rest,
                    scale: float, window: Optional[int], prefix_len: int,
                    kvh: int, quant: bool, profile: Optional[str]):
     if quant:
@@ -367,8 +386,8 @@ def _ragged_kernel(bt_ref, ss_ref, sb_ref, cu_ref, q_ref, k_ref, v_ref,
                     ).astype(o_ref.dtype)
 
 
-def _ragged_dma_kernel(bt_ref, ss_ref, sb_ref, cu_ref, pbs_ref, q_ref,
-                       qp_ref, pp_ref, *rest,
+def _ragged_dma_kernel(bt_ref, ly_ref, ss_ref, sb_ref, cu_ref, pbs_ref,
+                       q_ref, qp_ref, pp_ref, *rest,
                        scale: float, window: Optional[int], prefix_len: int,
                        kvh: int, quant: bool, nbuf: int,
                        profile: Optional[str]):
@@ -393,12 +412,13 @@ def _ragged_dma_kernel(bt_ref, ss_ref, sb_ref, cu_ref, pbs_ref, q_ref,
     i = pl.program_id(0)
     n = pl.num_programs(0)
     b = ss_ref[i]
+    layer = ly_ref[0]
 
     def _copies(t, slot):
         blk = pbs_ref[t]
-        return [pltpu.make_async_copy(k_hbm.at[blk], kbuf.at[slot],
+        return [pltpu.make_async_copy(k_hbm.at[layer, blk], kbuf.at[slot],
                                       ksem.at[slot]),
-                pltpu.make_async_copy(v_hbm.at[blk], vbuf.at[slot],
+                pltpu.make_async_copy(v_hbm.at[layer, blk], vbuf.at[slot],
                                       vsem.at[slot])]
 
     @pl.when(i == cu_ref[b])
@@ -452,6 +472,7 @@ def ragged_paged_verify_attn_pallas(q: jax.Array, k: jax.Array,
                                     v: jax.Array, q_pos: jax.Array,
                                     pos: jax.Array,
                                     block_tables: jax.Array,
+                                    layer: int | jax.Array,
                                     cu_blocks: jax.Array,
                                     window: Optional[int] = None,
                                     prefix_len: int = 0,
@@ -464,9 +485,10 @@ def ragged_paged_verify_attn_pallas(q: jax.Array, k: jax.Array,
                                     interpret: bool = False) -> jax.Array:
     """Verify-step attention against the paged pool, fused, *ragged* grid.
 
-    Same operands and masking contract as :func:`paged_verify_attn_pallas`
-    plus ``cu_blocks [B + 1]`` — the host-computed cumulative grid-step
-    counts (per-slot steps = ``max(live blocks, 1)``; see
+    Same operands (the whole ``[nL, NB, ...]`` pool plus ``layer``) and
+    masking contract as :func:`paged_verify_attn_pallas` plus ``cu_blocks
+    [B + 1]`` — the host-computed cumulative grid-step counts (per-slot
+    steps = ``max(live blocks, 1)``; see
     ``kernels/tuning.py host_cu_blocks``).  The grid is one flat run of
     ``cu_blocks[B]`` steps, so dead table entries cost nothing; per-slot
     blocks are visited in ascending logical order, making the output
@@ -486,19 +508,20 @@ def ragged_paged_verify_attn_pallas(q: jax.Array, k: jax.Array,
       DMA-vs-compute split.  Output is garbage in either mode.
     """
     B, T, H, hd = q.shape
-    bs, KVH = k.shape[1], k.shape[2]
+    bs, KVH = k.shape[2], k.shape[3]
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     qf, qpf, GT, unfold = _fold_q(q, q_pos, KVH)
     ss, sb, pbs = ragged_plan(block_tables, cu_blocks)
+    ly = _layer_operand(layer)
     total = cu_blocks.astype(jnp.int32)[block_tables.shape[0]]
     quant = k_scale is not None
 
     # index maps see the grid index plus every scalar-prefetch operand,
     # in positional order
-    def _q_map(i, bt, ss, sb, cu):
+    def _q_map(i, bt, ly, ss, sb, cu):
         return (ss[i], 0, 0, 0)
 
-    def _qp_map(i, bt, ss, sb, cu):
+    def _qp_map(i, bt, ly, ss, sb, cu):
         return (ss[i], 0, 0)
 
     kwargs = {}
@@ -508,28 +531,28 @@ def ragged_paged_verify_attn_pallas(q: jax.Array, k: jax.Array,
     pos3 = pos[:, None, :]                               # [NB, 1, bs] rows
 
     if num_buffers >= 2:
-        def _q_map_d(i, bt, ss, sb, cu, pbs):
+        def _q_map_d(i, bt, ly, ss, sb, cu, pbs):
             return (ss[i], 0, 0, 0)
 
-        def _qp_map_d(i, bt, ss, sb, cu, pbs):
+        def _qp_map_d(i, bt, ly, ss, sb, cu, pbs):
             return (ss[i], 0, 0)
 
-        def _pos_map_d(i, bt, ss, sb, cu, pbs):
+        def _pos_map_d(i, bt, ly, ss, sb, cu, pbs):
             return (pbs[i], 0, 0)
 
-        def _scale_map_d(i, bt, ss, sb, cu, pbs):
-            return (pbs[i], 0, 0)
+        def _scale_map_d(i, bt, ly, ss, sb, cu, pbs):
+            return (ly[0], pbs[i], 0, 0)
 
         in_specs = [
             pl.BlockSpec((1, KVH, GT, hd), _q_map_d),
             pl.BlockSpec((1, GT, 1), _qp_map_d),
             pl.BlockSpec((1, 1, bs), _pos_map_d),
         ]
-        args = [block_tables, ss, sb, cu_blocks.astype(jnp.int32), pbs,
+        args = [block_tables, ly, ss, sb, cu_blocks.astype(jnp.int32), pbs,
                 qf, qpf, pos3]
         if quant:
-            in_specs += [pl.BlockSpec((1, bs, KVH), _scale_map_d),
-                         pl.BlockSpec((1, bs, KVH), _scale_map_d)]
+            in_specs += [pl.BlockSpec((None, 1, bs, KVH), _scale_map_d),
+                         pl.BlockSpec((None, 1, bs, KVH), _scale_map_d)]
             args += [k_scale, v_scale]
         in_specs += [pl.BlockSpec(memory_space=pl.ANY),
                      pl.BlockSpec(memory_space=pl.ANY)]
@@ -546,40 +569,40 @@ def ragged_paged_verify_attn_pallas(q: jax.Array, k: jax.Array,
                                  kvh=KVH, quant=quant, nbuf=D,
                                  profile=profile)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
+            num_scalar_prefetch=6,
             grid=(total,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, KVH, GT, hd), _q_map_d),
             scratch_shapes=scratch,
         )
     else:
-        def _kv_map(i, bt, ss, sb, cu):
-            return (jnp.maximum(bt[ss[i], sb[i]], 0), 0, 0, 0)
+        def _kv_map(i, bt, ly, ss, sb, cu):
+            return (ly[0], jnp.maximum(bt[ss[i], sb[i]], 0), 0, 0, 0)
 
-        def _pos_map(i, bt, ss, sb, cu):
+        def _pos_map(i, bt, ly, ss, sb, cu):
             return (jnp.maximum(bt[ss[i], sb[i]], 0), 0, 0)
 
-        def _scale_map(i, bt, ss, sb, cu):
-            return (jnp.maximum(bt[ss[i], sb[i]], 0), 0, 0)
+        def _scale_map(i, bt, ly, ss, sb, cu):
+            return (ly[0], jnp.maximum(bt[ss[i], sb[i]], 0), 0, 0)
 
         in_specs = [
             pl.BlockSpec((1, KVH, GT, hd), _q_map),
-            pl.BlockSpec((1, bs, KVH, hd), _kv_map),
-            pl.BlockSpec((1, bs, KVH, hd), _kv_map),
+            pl.BlockSpec((None, 1, bs, KVH, hd), _kv_map),
+            pl.BlockSpec((None, 1, bs, KVH, hd), _kv_map),
             pl.BlockSpec((1, GT, 1), _qp_map),
             pl.BlockSpec((1, 1, bs), _pos_map),
         ]
-        args = [block_tables, ss, sb, cu_blocks.astype(jnp.int32),
+        args = [block_tables, ly, ss, sb, cu_blocks.astype(jnp.int32),
                 qf, k, v, qpf, pos3]
         if quant:
-            in_specs += [pl.BlockSpec((1, bs, KVH), _scale_map),
-                         pl.BlockSpec((1, bs, KVH), _scale_map)]
+            in_specs += [pl.BlockSpec((None, 1, bs, KVH), _scale_map),
+                         pl.BlockSpec((None, 1, bs, KVH), _scale_map)]
             args += [k_scale, v_scale]
         kern = functools.partial(_ragged_kernel, scale=scale, window=window,
                                  prefix_len=prefix_len, kvh=KVH,
                                  quant=quant, profile=profile)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=(total,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, KVH, GT, hd), _q_map),

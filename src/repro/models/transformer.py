@@ -335,52 +335,76 @@ class DecoderLM:
         return out, new_lcache
 
     def _attn_paged(self, lp: Dict, x: jax.Array, positions: jax.Array,
-                    lcache: Dict, pos_arr: jax.Array, pb: jax.Array,
-                    off: jax.Array, bt: jax.Array, prefix_len: int,
-                    cu_blocks: Optional[jax.Array] = None,
+                    pool: Dict, layer: jax.Array, pos_arr: jax.Array,
+                    pb: jax.Array, off: jax.Array, bt: jax.Array,
+                    prefix_len: int, cu_blocks: Optional[jax.Array] = None,
                     ) -> Tuple[jax.Array, Dict]:
-        """Paged incremental attention: scatter this step's KV rows through
-        the block table (``pb``/``off`` physical addresses, out-of-range =>
-        dropped write), then attend against the pool via
+        """Paged incremental attention for layer ``layer`` of the whole
+        pool (``[nL, NB, bs, ...]`` leaves, carried through the layer scan
+        by :meth:`_paged_forward`): scatter this step's KV rows in place at
+        ``(layer, pb, off)`` (``pb``/``off`` physical addresses through the
+        block table, out-of-range => dropped write), then attend via
         :func:`~repro.kernels.paged.paged_verify_attn` — the fused streaming
-        kernel or the gather reference per ``cfg.paged_fused``.  Shared by
-        the paged decode step, the paged prefill-chunk (prefix-extension)
-        forward, and the mixed verify+chunk launch, so all three ride the
-        same kernel.  ``cu_blocks [B + 1]`` (host-computed cumulative
-        grid-step counts) upgrades the fused path to the ragged grid —
-        steps = sum of live blocks instead of ``B * MAXB``.
+        kernel, reading ``(layer, block)`` tiles, or the gather reference
+        per ``cfg.paged_fused``.  No slice of the layer is ever taken, so
+        the donated pool is updated where it lies.  ``cu_blocks [B + 1]``
+        (host-computed cumulative grid-step counts) upgrades the fused path
+        to the ragged grid — steps = sum of live blocks instead of
+        ``B * MAXB``.  Returns (attn_out, updated pool).
         """
         c, a = self.cfg, self.cfg.attn
         q, k_new, v_new = self._qkv_gqa(lp, x, positions)
         if c.kv_quant:
             kq, ks = _quant_rows(k_new)
             vq, vs = _quant_rows(v_new)
-            new_lcache = {
-                "k": lcache["k"].at[pb, off].set(kq, mode="drop"),
-                "v": lcache["v"].at[pb, off].set(vq, mode="drop"),
-                "k_scale": lcache["k_scale"].at[pb, off].set(
-                    ks.astype(lcache["k_scale"].dtype), mode="drop"),
-                "v_scale": lcache["v_scale"].at[pb, off].set(
-                    vs.astype(lcache["v_scale"].dtype), mode="drop"),
-            }
-            out = paged_verify_attn(
-                q, new_lcache["k"], new_lcache["v"], positions, pos_arr, bt,
-                window=a.window, prefix_len=prefix_len,
-                k_scale=new_lcache["k_scale"],
-                v_scale=new_lcache["v_scale"], use_pallas=c.paged_fused,
-                cu_blocks=cu_blocks)
+            rows = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
         else:
-            new_lcache = {
-                "k": lcache["k"].at[pb, off].set(
-                    k_new.astype(lcache["k"].dtype), mode="drop"),
-                "v": lcache["v"].at[pb, off].set(
-                    v_new.astype(lcache["v"].dtype), mode="drop"),
-            }
-            out = paged_verify_attn(
-                q, new_lcache["k"], new_lcache["v"], positions, pos_arr, bt,
-                window=a.window, prefix_len=prefix_len,
-                use_pallas=c.paged_fused, cu_blocks=cu_blocks)
-        return jnp.einsum("bthk,hkd->btd", out, lp["wo"]), new_lcache
+            rows = {"k": k_new, "v": v_new}
+        pool = {n: pool[n].at[layer, pb, off].set(
+                    r.astype(pool[n].dtype), mode="drop")
+                for n, r in rows.items()}
+        out = paged_verify_attn(
+            q, pool["k"], pool["v"], positions, pos_arr, bt, layer,
+            window=a.window, prefix_len=prefix_len,
+            k_scale=pool.get("k_scale"), v_scale=pool.get("v_scale"),
+            use_pallas=c.paged_fused, cu_blocks=cu_blocks)
+        return jnp.einsum("bthk,hkd->btd", out, lp["wo"]), pool
+
+    def _paged_forward(self, params: Params, x: jax.Array, cache: Dict,
+                       positions: jax.Array, pos_arr: jax.Array,
+                       pb: jax.Array, off: jax.Array, bt: jax.Array,
+                       cu_blocks: Optional[jax.Array]) -> Tuple[jax.Array, Dict]:
+        """The layers and output head of the three paged forwards, from the
+        embedded ``x`` and the step's write addresses.  The pool leaves
+        (``k``/``v``, plus ``k_scale``/``v_scale`` for int8) ride the layer
+        scan's carry, not its ``xs``/``ys``: each layer writes its rows in
+        place and the kernel reads the layer through its index, so the scan
+        never slices a layer out of the pool nor stacks one back.  ``bt`` is
+        the table attention reads through; the returned cache keeps
+        ``cache["bt"]``.  Returns (logits, updated cache)."""
+        c = self.cfg
+        prefix_len = c.prefix_len if c.bidirectional_prefix else 0
+
+        def body(carry, xs):
+            h, pool = carry
+            lp, li = xs
+            hn = cm.rms_norm(h, lp["attn_norm"], c.norm_eps)
+            a_out, pool = self._attn_paged(lp, hn, positions, pool, li,
+                                           pos_arr, pb, off, bt, prefix_len,
+                                           cu_blocks)
+            h = h + shard(a_out, "data", None, None)
+            m_out, _ = self._mlp(lp, cm.rms_norm(h, lp["mlp_norm"], c.norm_eps))
+            h = h + shard(m_out, "data", None, None)
+            return (h, pool), None
+
+        pool = {k: v for k, v in cache.items() if k not in ("pos", "bt")}
+        layers = jnp.arange(c.n_layers, dtype=jnp.int32)
+        (x, pool), _ = jax.lax.scan(body, (x, pool),
+                                    (params["layers"], layers))
+        x = cm.rms_norm(x, params["final_norm"], c.norm_eps)
+        table = params["embed"] if c.tie_embeddings else params["unembed"]
+        logits = cm.unembed(x, table, c.vocab_size)
+        return logits, dict(pool, pos=pos_arr, bt=cache["bt"])
 
     # ------------------------------------------------------------------
     # MLP
@@ -624,7 +648,6 @@ class DecoderLM:
         masked out, so the same compiled step serves every occupancy level —
         exactly the contiguous slot-pool contract.
         """
-        c, a = self.cfg, self.cfg.attn
         B, T = tokens.shape
         NB, bs = cache["pos"].shape
         bt = cache["bt"]                                        # [B, MAXB]
@@ -636,26 +659,8 @@ class DecoderLM:
         pb = jnp.take_along_axis(bt, blk, axis=1)               # [B, T]
         pb = jnp.where(pb < 0, NB, pb)                          # NB => dropped
         pos_arr = cache["pos"].at[pb, off].set(positions, mode="drop")
-        prefix_len = c.prefix_len if c.bidirectional_prefix else 0
-
-        def layer(carry, xs):
-            h = carry
-            lp, lcache = xs
-            hn = cm.rms_norm(h, lp["attn_norm"], c.norm_eps)
-            a_out, new_lcache = self._attn_paged(lp, hn, positions, lcache,
-                                                 pos_arr, pb, off, bt,
-                                                 prefix_len, cu_blocks)
-            h = h + shard(a_out, "data", None, None)
-            m_out, _ = self._mlp(lp, cm.rms_norm(h, lp["mlp_norm"], c.norm_eps))
-            h = h + shard(m_out, "data", None, None)
-            return h, new_lcache
-
-        layer_caches = {k: v for k, v in cache.items() if k not in ("pos", "bt")}
-        x, new_caches = jax.lax.scan(layer, x, (params["layers"], layer_caches))
-        x = cm.rms_norm(x, params["final_norm"], c.norm_eps)
-        table = params["embed"] if c.tie_embeddings else params["unembed"]
-        logits = cm.unembed(x, table, c.vocab_size)
-        return logits, dict(new_caches, pos=pos_arr, bt=bt)
+        return self._paged_forward(params, x, cache, positions, pos_arr,
+                                   pb, off, bt, cu_blocks)
 
     def decode_step_mixed(self, params: Params, tokens: jax.Array,
                           cache: Dict, seq_lens: jax.Array,
@@ -684,7 +689,6 @@ class DecoderLM:
         the chunk row are meaningless (the engine's accept mask already
         zeroes pending slots); callers slice ``[:, :verify_len]``.
         """
-        c = self.cfg
         B, T = tokens.shape
         NB, bs = cache["pos"].shape
         bt = cache["bt"]                                        # [B, MAXB]
@@ -705,26 +709,8 @@ class DecoderLM:
         pb = jnp.where((pb < 0) | ~valid, NB, pb)               # NB => dropped
         pos_arr = cache["pos"].at[pb, off].set(
             jnp.where(valid, positions, -1), mode="drop")
-        prefix_len = c.prefix_len if c.bidirectional_prefix else 0
-
-        def layer(carry, xs):
-            h = carry
-            lp, lcache = xs
-            hn = cm.rms_norm(h, lp["attn_norm"], c.norm_eps)
-            a_out, new_lcache = self._attn_paged(lp, hn, positions, lcache,
-                                                 pos_arr, pb, off, bt_eff,
-                                                 prefix_len, cu_blocks)
-            h = h + shard(a_out, "data", None, None)
-            m_out, _ = self._mlp(lp, cm.rms_norm(h, lp["mlp_norm"], c.norm_eps))
-            h = h + shard(m_out, "data", None, None)
-            return h, new_lcache
-
-        layer_caches = {k: v for k, v in cache.items() if k not in ("pos", "bt")}
-        x, new_caches = jax.lax.scan(layer, x, (params["layers"], layer_caches))
-        x = cm.rms_norm(x, params["final_norm"], c.norm_eps)
-        table = params["embed"] if c.tie_embeddings else params["unembed"]
-        logits = cm.unembed(x, table, c.vocab_size)
-        return logits, dict(new_caches, pos=pos_arr, bt=bt)
+        return self._paged_forward(params, x, cache, positions, pos_arr,
+                                   pb, off, bt_eff, cu_blocks)
 
     # ------------------------------------------------------------------
     # chunked prefill (prefix extension)
@@ -801,7 +787,6 @@ class DecoderLM:
         reference per ``cfg.paged_fused`` (kernels/paged.py), masking
         unchanged.  This is the fused prefix-extension chunk forward: the
         chunk's q rows stream the pool exactly like a verify step's."""
-        c, a = self.cfg, self.cfg.attn
         B, T = tokens.shape
         NB, bs = cache["pos"].shape
         bt = cache["bt"]                                        # [B, MAXB]
@@ -815,26 +800,8 @@ class DecoderLM:
         pb = jnp.where((pb < 0) | ~valid, NB, pb)               # NB => dropped
         pos_arr = cache["pos"].at[pb, off].set(
             jnp.where(valid, positions, -1), mode="drop")
-        prefix_len = c.prefix_len if c.bidirectional_prefix else 0
-
-        def layer(carry, xs):
-            h = carry
-            lp, lcache = xs
-            hn = cm.rms_norm(h, lp["attn_norm"], c.norm_eps)
-            a_out, new_lcache = self._attn_paged(lp, hn, positions, lcache,
-                                                 pos_arr, pb, off, bt,
-                                                 prefix_len, cu_blocks)
-            h = h + shard(a_out, "data", None, None)
-            m_out, _ = self._mlp(lp, cm.rms_norm(h, lp["mlp_norm"], c.norm_eps))
-            h = h + shard(m_out, "data", None, None)
-            return h, new_lcache
-
-        layer_caches = {k: v for k, v in cache.items() if k not in ("pos", "bt")}
-        x, new_caches = jax.lax.scan(layer, x, (params["layers"], layer_caches))
-        x = cm.rms_norm(x, params["final_norm"], c.norm_eps)
-        table = params["embed"] if c.tie_embeddings else params["unembed"]
-        logits = cm.unembed(x, table, c.vocab_size)
-        return logits, dict(new_caches, pos=pos_arr, bt=bt)
+        return self._paged_forward(params, x, cache, positions, pos_arr,
+                                   pb, off, bt, cu_blocks)
 
     @staticmethod
     def commit(cache_out: Dict, accept_idx: jax.Array) -> Dict:

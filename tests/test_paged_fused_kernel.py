@@ -36,10 +36,12 @@ def _rand(shape, dtype=jnp.float32, k=0):
 def _pool(B, lens, NB, bs, MAXB, KVH, hd, seed=0, holes=()):
     """Build a ragged paged pool: per-slot block tables (optionally with
     interior -1 holes — e.g. a preempted slot's partially-rebuilt table),
-    the pool pos map, and k/v pool arrays with garbage in unowned blocks."""
+    the pool pos map, and k/v pool arrays with garbage in unowned blocks.
+    k/v hold one layer, ``[1, NB, bs, KVH, hd]``: the kernels read it at
+    layer 0."""
     rng = np.random.default_rng(seed)
-    k = _rand((NB, bs, KVH, hd), k=seed + 1)
-    v = _rand((NB, bs, KVH, hd), k=seed + 2)
+    k = _rand((1, NB, bs, KVH, hd), k=seed + 1)
+    v = _rand((1, NB, bs, KVH, hd), k=seed + 2)
     bt = np.full((B, MAXB), -1, np.int32)
     pos = np.full((NB, bs), -1, np.int32)
     order = rng.permutation(NB)
@@ -75,9 +77,10 @@ def test_fused_matches_gather_and_ref(T, H, KVH):
     k, v, bt, pos = _pool(B, lens, NB, bs, MAXB, KVH, hd, seed=3)
     q = _rand((B, T, H, hd), k=9)
     qp = _qpos(lens, T)
-    got = paged_verify_attn_pallas(q, k, v, qp, pos, bt, interpret=True)
-    via_gather = gather_verify_attn(q, k, v, qp, pos, bt, use_pallas=False)
-    kg, vg = gather_kv_blocks(k, v, bt)
+    got = paged_verify_attn_pallas(q, k, v, qp, pos, bt, 0, interpret=True)
+    via_gather = gather_verify_attn(q, k, v, qp, pos, bt, 0,
+                                    use_pallas=False)
+    kg, vg = gather_kv_blocks(k, v, bt, 0)
     want = KR.gqa_masked_ref(q, kg, vg, qp, gather_key_positions(pos, bt))
     np.testing.assert_allclose(np.asarray(got), np.asarray(via_gather),
                                rtol=2e-5, atol=2e-5)
@@ -98,8 +101,8 @@ def test_fused_ragged_tables_with_holes_and_empty_slot():
     assert int(np.asarray(bt)[3, 1]) == -1 and int(np.asarray(bt)[3, 2]) >= 0
     q = _rand((B, T, H, hd), k=11)
     qp = _qpos(lens, T)
-    got = paged_verify_attn_pallas(q, k, v, qp, pos, bt, interpret=True)
-    want = gather_verify_attn(q, k, v, qp, pos, bt, use_pallas=False)
+    got = paged_verify_attn_pallas(q, k, v, qp, pos, bt, 0, interpret=True)
+    want = gather_verify_attn(q, k, v, qp, pos, bt, 0, use_pallas=False)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
     # the empty slot's rows are fully masked -> exact zeros on both paths
@@ -113,9 +116,9 @@ def test_fused_window_and_prefix_masking(window, prefix):
     k, v, bt, pos = _pool(B, lens, NB, bs, MAXB, KVH, hd, seed=7)
     q = _rand((B, T, H, hd), k=13)
     qp = _qpos(lens, T)
-    got = paged_verify_attn_pallas(q, k, v, qp, pos, bt, window=window,
+    got = paged_verify_attn_pallas(q, k, v, qp, pos, bt, 0, window=window,
                                    prefix_len=prefix, interpret=True)
-    want = gather_verify_attn(q, k, v, qp, pos, bt, window=window,
+    want = gather_verify_attn(q, k, v, qp, pos, bt, 0, window=window,
                               prefix_len=prefix, use_pallas=False)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
@@ -125,16 +128,16 @@ def test_fused_int8_scales_dequant_in_kernel():
     B, T, H, KVH, hd, NB, bs, MAXB = 2, 4, 4, 2, 32, 10, 8, 3
     lens = [19, 8]
     k, v, bt, pos = _pool(B, lens, NB, bs, MAXB, KVH, hd, seed=17)
-    ks = jnp.max(jnp.abs(k), -1) / 127.0 + 1e-8          # [NB, bs, KVH]
+    ks = jnp.max(jnp.abs(k), -1) / 127.0 + 1e-8          # [1, NB, bs, KVH]
     vs = jnp.max(jnp.abs(v), -1) / 127.0 + 1e-8
     kq = jnp.clip(jnp.round(k / ks[..., None]), -127, 127).astype(jnp.int8)
     vq = jnp.clip(jnp.round(v / vs[..., None]), -127, 127).astype(jnp.int8)
     q = _rand((B, T, H, hd), k=19)
     qp = _qpos(lens, T)
-    got = paged_verify_attn_pallas(q, kq, vq, qp, pos, bt,
+    got = paged_verify_attn_pallas(q, kq, vq, qp, pos, bt, 0,
                                    k_scale=ks, v_scale=vs, interpret=True)
-    want = gather_verify_attn(q, kq, vq, qp, pos, bt, k_scale=ks, v_scale=vs,
-                              use_pallas=False)
+    want = gather_verify_attn(q, kq, vq, qp, pos, bt, 0, k_scale=ks,
+                              v_scale=vs, use_pallas=False)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
 
@@ -147,9 +150,10 @@ def test_paged_verify_attn_dispatch_modes_agree():
     k, v, bt, pos = _pool(B, lens, NB, bs, MAXB, KVH, hd, seed=23)
     q = _rand((B, T, H, hd), k=29)
     qp = _qpos(lens, T)
-    ref = paged_verify_attn(q, k, v, qp, pos, bt, use_pallas=False)
-    fused = paged_verify_attn(q, k, v, qp, pos, bt, use_pallas=True)
-    gather_pallas = gather_verify_attn(q, k, v, qp, pos, bt, use_pallas=True)
+    ref = paged_verify_attn(q, k, v, qp, pos, bt, 0, use_pallas=False)
+    fused = paged_verify_attn(q, k, v, qp, pos, bt, 0, use_pallas=True)
+    gather_pallas = gather_verify_attn(q, k, v, qp, pos, bt, 0,
+                                       use_pallas=True)
     np.testing.assert_allclose(np.asarray(fused), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(gather_pallas), np.asarray(ref),
@@ -168,9 +172,9 @@ def test_gather_single_block_fast_path_matches_general():
     pos = jnp.where(_rand((NB, bs), k=34) > 0,
                     jnp.arange(bs, dtype=jnp.int32)[None, :], -1)
     bt1 = jnp.asarray([[3], [-1], [0]], jnp.int32)       # MAXB == 1
-    kg, vg = gather_kv_blocks(k, v, bt1)
+    kg, vg = gather_kv_blocks(k[None], v[None], bt1, 0)
     kp = gather_key_positions(pos, bt1)
-    sg = gather_scales(scale, bt1)
+    sg = gather_scales(scale[None], bt1, 0)
     assert kg.shape == (3, bs, KVH, hd) and kp.shape == (3, bs)
     safe = np.maximum(np.asarray(bt1)[:, 0], 0)
     np.testing.assert_array_equal(np.asarray(kg), np.asarray(k)[safe])

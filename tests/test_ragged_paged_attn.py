@@ -43,10 +43,11 @@ def _rand(shape, dtype=jnp.float32, k=0):
 def _pool(B, lens, NB, bs, MAXB, KVH, hd, seed=0, holes=()):
     """Ragged paged pool (same construction as test_paged_fused_kernel):
     block tables with optional interior -1 holes, pool pos map, and k/v
-    pools whose unowned blocks hold garbage."""
+    pools whose unowned blocks hold garbage.  k/v hold one layer,
+    ``[1, NB, bs, KVH, hd]``: the kernels read it at layer 0."""
     rng = np.random.default_rng(seed)
-    k = _rand((NB, bs, KVH, hd), k=seed + 1)
-    v = _rand((NB, bs, KVH, hd), k=seed + 2)
+    k = _rand((1, NB, bs, KVH, hd), k=seed + 1)
+    v = _rand((1, NB, bs, KVH, hd), k=seed + 2)
     bt = np.full((B, MAXB), -1, np.int32)
     pos = np.full((NB, bs), -1, np.int32)
     order = rng.permutation(NB)
@@ -105,11 +106,11 @@ def test_ragged_bit_identical_to_dense_fused(pattern):
     dense fused kernel — and allclose to the gather reference — on every
     raggedness pattern."""
     q, k, v, qp, pos, bt, cu = _case(pattern)
-    ragged = ragged_paged_verify_attn_pallas(q, k, v, qp, pos, bt, cu,
+    ragged = ragged_paged_verify_attn_pallas(q, k, v, qp, pos, bt, 0, cu,
                                              interpret=True)
-    dense = paged_verify_attn_pallas(q, k, v, qp, pos, bt, interpret=True)
+    dense = paged_verify_attn_pallas(q, k, v, qp, pos, bt, 0, interpret=True)
     np.testing.assert_array_equal(np.asarray(ragged), np.asarray(dense))
-    want = np.asarray(gather_verify_attn(q, k, v, qp, pos, bt,
+    want = np.asarray(gather_verify_attn(q, k, v, qp, pos, bt, 0,
                                          use_pallas=False))
     got = np.asarray(ragged)
     live = np.asarray(qp) >= 0                    # dead rows: ragged/dense
@@ -123,9 +124,9 @@ def test_manual_dma_depths_bit_identical(nbuf):
     (num_buffers=0) output bit-for-bit on the extreme pattern — buffering
     is a schedule, never a numeric."""
     q, k, v, qp, pos, bt, cu = _case("extreme")
-    base = ragged_paged_verify_attn_pallas(q, k, v, qp, pos, bt, cu,
+    base = ragged_paged_verify_attn_pallas(q, k, v, qp, pos, bt, 0, cu,
                                            interpret=True)
-    dma = ragged_paged_verify_attn_pallas(q, k, v, qp, pos, bt, cu,
+    dma = ragged_paged_verify_attn_pallas(q, k, v, qp, pos, bt, 0, cu,
                                           num_buffers=nbuf, interpret=True)
     np.testing.assert_array_equal(np.asarray(dma), np.asarray(base))
 
@@ -135,19 +136,19 @@ def test_ragged_int8_window_prefix(nbuf):
     """int8 pool scales (dequant in-kernel, including through the manual-DMA
     scale stream) plus sliding-window and bidirectional-prefix masking."""
     q, k, v, qp, pos, bt, cu = _case("holes", T=4)
-    ks = jnp.max(jnp.abs(k), -1) / 127.0 + 1e-8          # [NB, bs, KVH]
+    ks = jnp.max(jnp.abs(k), -1) / 127.0 + 1e-8          # [1, NB, bs, KVH]
     vs = jnp.max(jnp.abs(v), -1) / 127.0 + 1e-8
     kq = jnp.clip(jnp.round(k / ks[..., None]), -127, 127).astype(jnp.int8)
     vq = jnp.clip(jnp.round(v / vs[..., None]), -127, 127).astype(jnp.int8)
     for kw in ({}, {"window": 10, "prefix_len": 5}):
         got = ragged_paged_verify_attn_pallas(
-            q, kq, vq, qp, pos, bt, cu, k_scale=ks, v_scale=vs,
+            q, kq, vq, qp, pos, bt, 0, cu, k_scale=ks, v_scale=vs,
             num_buffers=nbuf, interpret=True, **kw)
-        dense = paged_verify_attn_pallas(q, kq, vq, qp, pos, bt,
+        dense = paged_verify_attn_pallas(q, kq, vq, qp, pos, bt, 0,
                                          k_scale=ks, v_scale=vs,
                                          interpret=True, **kw)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(dense))
-        want = gather_verify_attn(q, kq, vq, qp, pos, bt, k_scale=ks,
+        want = gather_verify_attn(q, kq, vq, qp, pos, bt, 0, k_scale=ks,
                                   v_scale=vs, use_pallas=False, **kw)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-4, atol=2e-4)
@@ -158,17 +159,73 @@ def test_dispatcher_routes_ragged_on_cu_blocks():
     kernel (same numbers as calling it directly); without cu_blocks the
     dense kernel answers; forced-ref ignores cu_blocks entirely."""
     q, k, v, qp, pos, bt, cu = _case("basic")
-    via_dispatch = paged_verify_attn(q, k, v, qp, pos, bt, use_pallas=True,
-                                     cu_blocks=cu,
+    via_dispatch = paged_verify_attn(q, k, v, qp, pos, bt, 0,
+                                     use_pallas=True, cu_blocks=cu,
                                      config=RaggedConfig(num_buffers=2))
-    direct = ragged_paged_verify_attn_pallas(q, k, v, qp, pos, bt, cu,
+    direct = ragged_paged_verify_attn_pallas(q, k, v, qp, pos, bt, 0, cu,
                                              num_buffers=2, interpret=True)
     np.testing.assert_array_equal(np.asarray(via_dispatch),
                                   np.asarray(direct))
-    ref = paged_verify_attn(q, k, v, qp, pos, bt, use_pallas=False,
+    ref = paged_verify_attn(q, k, v, qp, pos, bt, 0, use_pallas=False,
                             cu_blocks=cu)
     np.testing.assert_allclose(np.asarray(via_dispatch), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# the layer axis: the model's layer scan hands every kernel the whole
+# [nL, NB, ...] pool and the layer index
+
+
+N_LAYERS = 3
+
+
+def _layered_case(quant):
+    """The "holes" case over an N_LAYERS-layer pool with distinct data in
+    every layer: bf16 tiles, or int8 tiles with their dequant scales."""
+    q, k, _, qp, pos, bt, cu = _case("holes", T=4)
+    shape = (N_LAYERS,) + k.shape[1:]
+    k, v = _rand(shape, k=101), _rand(shape, k=102)
+    if not quant:
+        bf = jnp.bfloat16
+        return q.astype(bf), k.astype(bf), v.astype(bf), None, None, \
+            qp, pos, bt, cu
+    ks = jnp.max(jnp.abs(k), -1) / 127.0 + 1e-8          # [nL, NB, bs, KVH]
+    vs = jnp.max(jnp.abs(v), -1) / 127.0 + 1e-8
+    kq = jnp.clip(jnp.round(k / ks[..., None]), -127, 127).astype(jnp.int8)
+    vq = jnp.clip(jnp.round(v / vs[..., None]), -127, 127).astype(jnp.int8)
+    return q, kq, vq, ks, vs, qp, pos, bt, cu
+
+
+def _attend(kernel, q, k, v, qp, pos, bt, layer, cu, ks, vs):
+    kw = dict(k_scale=ks, v_scale=vs)
+    if kernel == "gather":
+        return gather_verify_attn(q, k, v, qp, pos, bt, layer,
+                                  use_pallas=False, **kw)
+    if kernel == "dense":
+        return paged_verify_attn_pallas(q, k, v, qp, pos, bt, layer,
+                                        interpret=True, **kw)
+    nbuf = {"ragged": 0, "dma2": 2, "dma4": 4}[kernel]
+    return ragged_paged_verify_attn_pallas(q, k, v, qp, pos, bt, layer, cu,
+                                           num_buffers=nbuf, interpret=True,
+                                           **kw)
+
+
+@pytest.mark.parametrize("layer", [0, N_LAYERS // 2, N_LAYERS - 1])
+@pytest.mark.parametrize("dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("kernel",
+                         ["ragged", "dma2", "dma4", "dense", "gather"])
+def test_layer_of_pool_equals_that_layer_alone(kernel, dtype, layer):
+    """Every paged attention path reading ``layer`` of a multi-layer pool
+    (the index a traced int32, as inside the layer scan) is bit-identical
+    to the same path on that layer's pool alone, read at layer 0.  The
+    layers hold distinct data, so reading any other layer would differ."""
+    q, k, v, ks, vs, qp, pos, bt, cu = _layered_case(dtype == "int8")
+    got = _attend(kernel, q, k, v, qp, pos, bt, jnp.int32(layer), cu, ks, vs)
+    alone = lambda a: None if a is None else a[layer][None]
+    want = _attend(kernel, q, alone(k), alone(v), qp, pos, bt, 0, cu,
+                   alone(ks), alone(vs))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------

@@ -12,7 +12,9 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU compiler library, and every test
 worker imports this file.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,14 +23,17 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import AxisType, SingleDeviceSharding
 
+from repro.configs import registry as R
+from repro.kernels import ops
 from repro.kernels.ops import batch_partitioned, partition_mesh
 from repro.kernels.paged_verify_attn import (paged_verify_attn_pallas,
                                              ragged_paged_verify_attn_pallas)
 from repro.kernels.spec_verify_attn import spec_verify_attn_pallas
 
-# internlm2-1.8b: 16 heads over 8 KV heads of 128; serving pool block 16,
-# 1024 tokens per slot, 8 slots, verify at s = 3 (T = 4)
-B, T, H, KVH, HD, BS, MAXB = 8, 4, 16, 8, 128, 16, 64
+# internlm2-1.8b: 24 layers, 16 heads over 8 KV heads of 128; serving pool
+# block 16, 1024 tokens per slot, 8 slots, verify at s = 3 (T = 4).  The
+# kernels read one layer of the whole [NL, NB, ...] pool.
+NL, B, T, H, KVH, HD, BS, MAXB = 24, 8, 4, 16, 8, 128, 16, 64
 NB = B * MAXB
 BF, I32 = jnp.bfloat16, jnp.int32
 
@@ -57,11 +62,11 @@ def one_chip(topo):
 
 def _paged_args(sh, dtype=BF):
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
-    return dict(q=s((B, T, H, HD), BF), k=s((NB, BS, KVH, HD), dtype),
-                v=s((NB, BS, KVH, HD), dtype), q_pos=s((B, T), I32),
-                pos=s((NB, BS), I32), bt=s((B, MAXB), I32),
-                cu=s((B + 1,), I32), ks=s((NB, BS, KVH), BF),
-                vs=s((NB, BS, KVH), BF))
+    return dict(q=s((B, T, H, HD), BF), k=s((NL, NB, BS, KVH, HD), dtype),
+                v=s((NL, NB, BS, KVH, HD), dtype), q_pos=s((B, T), I32),
+                pos=s((NB, BS), I32), bt=s((B, MAXB), I32), layer=s((), I32),
+                cu=s((B + 1,), I32), ks=s((NL, NB, BS, KVH), BF),
+                vs=s((NL, NB, BS, KVH), BF))
 
 
 def _compiled_text(fn, *args) -> str:
@@ -72,21 +77,22 @@ def _compiled_text(fn, *args) -> str:
 def test_ragged_paged_kernel_compiles_for_v5e(one_chip, num_buffers):
     a = _paged_args(one_chip)
     text = _compiled_text(
-        lambda q, k, v, qp, pos, bt, cu: ragged_paged_verify_attn_pallas(
-            q, k, v, qp, pos, bt, cu, num_buffers=num_buffers),
-        a["q"], a["k"], a["v"], a["q_pos"], a["pos"], a["bt"], a["cu"])
+        lambda q, k, v, qp, pos, bt, ly, cu: ragged_paged_verify_attn_pallas(
+            q, k, v, qp, pos, bt, ly, cu, num_buffers=num_buffers),
+        a["q"], a["k"], a["v"], a["q_pos"], a["pos"], a["bt"], a["layer"],
+        a["cu"])
     assert "tpu_custom_call" in text
 
 
 def test_ragged_paged_kernel_int8_pool_compiles_for_v5e(one_chip):
     a = _paged_args(one_chip, dtype=jnp.int8)
     text = _compiled_text(
-        lambda q, k, v, qp, pos, bt, cu, ks, vs:
-            ragged_paged_verify_attn_pallas(q, k, v, qp, pos, bt, cu,
+        lambda q, k, v, qp, pos, bt, ly, cu, ks, vs:
+            ragged_paged_verify_attn_pallas(q, k, v, qp, pos, bt, ly, cu,
                                             k_scale=ks, v_scale=vs,
                                             num_buffers=2),
-        a["q"], a["k"], a["v"], a["q_pos"], a["pos"], a["bt"], a["cu"],
-        a["ks"], a["vs"])
+        a["q"], a["k"], a["v"], a["q_pos"], a["pos"], a["bt"], a["layer"],
+        a["cu"], a["ks"], a["vs"])
     assert "tpu_custom_call" in text
 
 
@@ -95,18 +101,19 @@ def test_ragged_paged_kernel_with_vmem_limit_compiles_for_v5e(one_chip):
     params (``pltpu.CompilerParams``)."""
     a = _paged_args(one_chip)
     text = _compiled_text(
-        lambda q, k, v, qp, pos, bt, cu: ragged_paged_verify_attn_pallas(
-            q, k, v, qp, pos, bt, cu, vmem_limit_bytes=32 << 20),
-        a["q"], a["k"], a["v"], a["q_pos"], a["pos"], a["bt"], a["cu"])
+        lambda q, k, v, qp, pos, bt, ly, cu: ragged_paged_verify_attn_pallas(
+            q, k, v, qp, pos, bt, ly, cu, vmem_limit_bytes=32 << 20),
+        a["q"], a["k"], a["v"], a["q_pos"], a["pos"], a["bt"], a["layer"],
+        a["cu"])
     assert "tpu_custom_call" in text
 
 
 def test_dense_paged_kernel_compiles_for_v5e(one_chip):
     a = _paged_args(one_chip)
     text = _compiled_text(
-        lambda q, k, v, qp, pos, bt: paged_verify_attn_pallas(
-            q, k, v, qp, pos, bt),
-        a["q"], a["k"], a["v"], a["q_pos"], a["pos"], a["bt"])
+        lambda q, k, v, qp, pos, bt, ly: paged_verify_attn_pallas(
+            q, k, v, qp, pos, bt, ly),
+        a["q"], a["k"], a["v"], a["q_pos"], a["pos"], a["bt"], a["layer"])
     assert "tpu_custom_call" in text
 
 
@@ -170,18 +177,18 @@ def test_serving_kernels_are_named_in_the_compiled_program(one_chip, kernel):
     """Each serving kernel's custom call carries its own name in the
     compiled program, so a device trace names it; the ragged one keeps
     the scalar-prefetched s32 plan as its first operand."""
-    import re
     a = _paged_args(one_chip)
     if kernel == "ragged_paged_verify_attn":
         text = _compiled_text(
-            lambda q, k, v, qp, pos, bt, cu: ragged_paged_verify_attn_pallas(
-                q, k, v, qp, pos, bt, cu),
-            a["q"], a["k"], a["v"], a["q_pos"], a["pos"], a["bt"], a["cu"])
+            lambda q, k, v, qp, pos, bt, ly, cu:
+                ragged_paged_verify_attn_pallas(q, k, v, qp, pos, bt, ly, cu),
+            a["q"], a["k"], a["v"], a["q_pos"], a["pos"], a["bt"], a["layer"],
+            a["cu"])
     elif kernel == "paged_verify_attn":
         text = _compiled_text(
-            lambda q, k, v, qp, pos, bt: paged_verify_attn_pallas(
-                q, k, v, qp, pos, bt),
-            a["q"], a["k"], a["v"], a["q_pos"], a["pos"], a["bt"])
+            lambda q, k, v, qp, pos, bt, ly: paged_verify_attn_pallas(
+                q, k, v, qp, pos, bt, ly),
+            a["q"], a["k"], a["v"], a["q_pos"], a["pos"], a["bt"], a["layer"])
     else:
         s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
                                                     sharding=one_chip)
@@ -191,3 +198,50 @@ def test_serving_kernels_are_named_in_the_compiled_program(one_chip, kernel):
                               s((N, 8), I32), s((N, L), I32))
     calls = re.findall(rf"%{kernel}(?:\.\d+)? = .*custom-call\(", text)
     assert calls, text[:2000]
+
+
+# operations that move a buffer rather than compute one
+_MOVES = ("copy", "dynamic-slice", "dynamic-update-slice")
+_HLO_OP = re.compile(r"\s*(?:ROOT )?%(\S+) = (.*?) ([a-z][\w-]*)\(")
+
+
+def test_paged_step_writes_the_pool_in_place(one_chip, monkeypatch):
+    """The paged decode step at internlm2 widths (2 layers, a 512-block
+    pool, B = 8, T = 3, the ragged kernel, the pool donated) carries the
+    pool through its layer scan: no copy, dynamic-slice or
+    dynamic-update-slice yields a result of the pool's or one layer's
+    shape, its temporaries stay below one layer's K, and the donated pool
+    is still aliased to the output."""
+    # the dispatch sees the CPU here; steer it to the native kernel the
+    # chip runs
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    nl, nb, b, t = 2, 512, 8, 3
+    cfg = dataclasses.replace(R.get_config("internlm2-1.8b"), n_layers=nl,
+                              paged_fused=True)
+    model = R.build_model(cfg)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    params = jax.tree.map(lambda x: s(x.shape, x.dtype), model.shapes(BF))
+    cache = jax.tree.map(lambda x: s(x.shape, x.dtype), jax.eval_shape(
+        lambda: model.init_paged_cache(nb, BS, BF)))
+    cache["bt"] = s((b, MAXB), I32)
+    step = jax.jit(model._decode_step_paged, donate_argnums=(2,))
+    compiled = step.lower(params, s((b, t), I32), cache, s((b,), I32),
+                          s((b + 1,), I32)).compile()
+
+    layer = f"{nb},{BS},{KVH},{HD}]"
+    shapes = (f"[{nl},{layer}", f"[1,{layer}", f"[{layer}")
+    moved = []
+    for line in compiled.as_text().splitlines():
+        m = _HLO_OP.match(line)
+        if m is None:
+            continue
+        name, result, op = m.groups()
+        if (op.startswith(_MOVES) or any(mv in name for mv in _MOVES)) \
+                and any(sh in result for sh in shapes):
+            moved.append(line.strip()[:160])
+    assert not moved, "\n".join(moved)
+
+    layer_bytes = nb * BS * KVH * HD * jnp.dtype(BF).itemsize
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < layer_bytes, mem
+    assert mem.alias_size_in_bytes >= 2 * nl * layer_bytes, mem
