@@ -49,21 +49,26 @@ def main(argv=None, root=ROOT, require_tpu=True) -> int:
     from bench.arrivals import Mix
     from bench.weights import model_key
     from repro.serving.request import Request
-    conf = H.load_json(os.path.join(root, "bench", "configs",
-                                    args.config + ".json"))
+    conf = H.load_config(root, args.config)
     mix = Mix("calibration", "backlog", {
         "prompt": {"min": args.prompt, "max": args.prompt},
         "output": {"max": 512}})
     eng, tp, dp, be = H.build(conf, mix, args.seed)
-    from bench.weights import layer_weights
-    gen = jax.jit(lambda k, l: layer_weights(k, conf["target"], l))
-    for l in (0, conf["target"]["n_layers"] - 1):
-        one = gen(model_key(args.seed, 0), l)
-        diff = {n: int((np.asarray(tp["layers"][n][l]).astype(np.float32)
-                        != np.asarray(v).astype(np.float32)).sum())
-                for n, v in one.items()}
-        print(f"layer {l}: elements differing between the program's tree "
-              f"and the reference's regeneration: {diff}", flush=True)
+    from bench import archs
+    A = archs.of(conf["target"])
+    if not hasattr(A, "program_params"):
+        gen = jax.jit(lambda k, l: A.layer_weights(k, conf["target"], l))
+        for l in (0, conf["target"]["n_layers"] - 1):
+            one = gen(model_key(args.seed, 0), l)
+            both = jax.tree_util.tree_flatten_with_path(
+                jax.tree.map(lambda a, b: int(
+                    (np.asarray(a[l]).astype(np.float32)
+                     != np.asarray(b).astype(np.float32)).sum()),
+                    tp["layers"], one))[0]
+            diff = {".".join(str(k.key) for k in p): v for p, v in both}
+            print(f"layer {l}: elements differing between the program's "
+                  f"tree and the reference's regeneration: {diff}",
+                  flush=True)
     be._warm_step.update(S_VALUES)
     be._warm_prefill.add(H.next_pow2_bucket(args.prompt,
                                             conf["serving"]["cache_len"]))

@@ -4,10 +4,14 @@ the comparison that decides ``correct``.
 A cell of ``BENCHMARK.json`` names a configuration (``bench/configs/
 <name>.json``), a traffic mix (``bench/traffic/<name>.json``) and the
 chips it needs; the limits ``correct`` holds it to are in
-``bench/limits/<cell>.json``.  Each metric is read by
-``bench/metrics/<name>.py``, or, for a name ``<quantity>.<suffix>``, by
-the shared reader ``bench/metrics/<quantity>.py``.  Nothing here names a
-cell, a mix or a metric: a new one is new files and new entries.
+``bench/limits/<cell>.json``.  Each model section of a configuration names
+its architecture, ``bench/archs/<model>.py``, which builds the program's
+config, the seeded weights, the float32 reference layer and the flop
+counts (the contract is in ``bench/archs/__init__.py``).  Each metric is
+read by ``bench/metrics/<name>.py``, or, for a name
+``<quantity>.<suffix>``, by the shared reader
+``bench/metrics/<quantity>.py``.  Nothing here names a cell, a mix, a
+metric or an architecture: a new one is new files and new entries.
 
 The window drives ``serve_continuous_live`` (``ContinuousScheduler`` over
 a ``ContinuousEngineBackend``: paged pool, ragged kernel, chunked
@@ -94,49 +98,36 @@ def load_metric(root: str, name: str):
 # configuration
 
 
-WIDTH_KEYS = ("d_model", "d_ff", "vocab_size", "n_heads", "n_kv_heads",
-              "head_dim", "rope_theta", "norm_eps")
+def load_config(root: str, name: str) -> Dict:
+    """Configuration ``name`` of the checkout at ``root``, each model
+    section pointing at its architecture module there."""
+    from bench import archs
+    conf = load_json(os.path.join(bench_dir(root), "configs", name + ".json"))
+    return archs.resolve(conf, root)
 
 
 def model_configs(conf: Dict):
-    """The program's ``ModelConfig`` pair built from the file's numbers,
-    checked against the registry entry the file names."""
-    from repro.configs.base import AttnConfig, ModelConfig
-
-    def build(m, name):
-        return ModelConfig(
-            name=name, family="dense", n_layers=m["n_layers"],
-            d_model=m["d_model"], d_ff=m["d_ff"], vocab_size=m["vocab_size"],
-            attn=AttnConfig(n_heads=m["n_heads"], n_kv_heads=m["n_kv_heads"],
-                            head_dim=m["head_dim"],
-                            rope_theta=m["rope_theta"]),
-            norm_eps=m["norm_eps"], dtype=conf["dtype"])
-    t = build(conf["target"], conf["name"])
-    d = build(conf["draft"], conf["name"] + "-draft")
+    """The program's ``ModelConfig`` pair, built by each section's
+    architecture module from the file's numbers, and checked against the
+    registry entry the file names on every key the file states."""
+    from bench import archs
+    t = archs.of(conf["target"]).program_config(
+        conf["target"], conf["name"], conf["dtype"])
+    d = archs.of(conf["draft"]).program_config(
+        conf["draft"], conf["name"] + "-draft", conf["dtype"])
     if conf.get("arch"):
         from repro.configs import registry
-        for mine, reg in ((t, registry.get_config(conf["arch"])),
-                          (d, registry.get_draft_config(conf["arch"]))):
-            theirs = {"n_layers": reg.n_layers, "d_model": reg.d_model,
-                      "d_ff": reg.d_ff, "vocab_size": reg.vocab_size,
-                      "n_heads": reg.attn.n_heads,
-                      "n_kv_heads": reg.attn.n_kv_heads,
-                      "head_dim": reg.attn.head_dim,
-                      "rope_theta": reg.attn.rope_theta,
-                      "norm_eps": reg.norm_eps}
-            ours = {"n_layers": mine.n_layers, "d_model": mine.d_model,
-                    "d_ff": mine.d_ff, "vocab_size": mine.vocab_size,
-                    "n_heads": mine.attn.n_heads,
-                    "n_kv_heads": mine.attn.n_kv_heads,
-                    "head_dim": mine.attn.head_dim,
-                    "rope_theta": mine.attn.rope_theta,
-                    "norm_eps": mine.norm_eps}
-            for k in theirs:
-                if k in conf.get("reduced", []) and mine is t:
+        for sec, reg in (("target", registry.get_config(conf["arch"])),
+                         ("draft", registry.get_draft_config(conf["arch"]))):
+            m = conf[sec]
+            theirs = archs.of(m).registry_view(reg)
+            for k in m:
+                if k not in theirs or (k in conf.get("reduced", [])
+                                       and sec == "target"):
                     continue
-                if theirs[k] != ours[k]:
+                if theirs[k] != m[k]:
                     raise SystemExit(
-                        f"configuration {conf['name']}: {k} = {ours[k]} but "
+                        f"configuration {conf['name']}: {k} = {m[k]} but "
                         f"registry {conf['arch']} has {theirs[k]} and "
                         f"{k} is not in 'reduced'")
     return t, d

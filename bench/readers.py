@@ -8,6 +8,7 @@ from __future__ import annotations
 import statistics
 from typing import List, Optional
 
+from bench import archs
 from bench import flops as F
 from bench.stats import in_window, percentile, time_weighted_mean, tpot
 
@@ -92,13 +93,14 @@ def decode_batch_mean(run) -> Optional[float]:
 def window_flops(run) -> float:
     """Target flops of the window's prefilled and committed tokens."""
     m = run.conf["target"]
+    A = archs.of(m)
     total = 0.0
     for c in _calls(run):
         if c.kind in ("prefill", "chunk"):
-            total += F.tokens_flops(m, c.start, c.n, logits=False)
+            total += A.tokens_flops(m, c.start, c.n, logits=False)
     for st in _steps(run):
         for _, _, c, ctx, _ in st.rows:
-            total += F.tokens_flops(m, ctx - 1, c, logits=True)
+            total += A.tokens_flops(m, ctx - 1, c, logits=True)
     return total
 
 
@@ -116,13 +118,14 @@ def ragged_least_time(run) -> float:
     call per layer per verify step (each live row: s+1 queries against
     its context plus the step's writes) and per paged chunk."""
     m, bs = run.conf["target"], run.conf["serving"]["block_size"]
+    A = archs.of(m)
     t = 0.0
     for st in _steps(run):
         rows = [(st.s + 1, ctx + st.s) for _, _, _, ctx, _ in st.rows]
-        t += F.least_time(*F.ragged_call(m, rows, bs), run.peaks)[0]
+        t += F.least_time(*A.attn_call(m, rows, bs), run.peaks)[0]
     for c in _calls(run):
         if c.kind == "chunk":
-            t += F.least_time(*F.ragged_call(m, [(c.n, c.start + c.n)], bs),
+            t += F.least_time(*A.attn_call(m, [(c.n, c.start + c.n)], bs),
                               run.peaks)[0]
     return t * m["n_layers"]
 

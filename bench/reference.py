@@ -1,13 +1,13 @@
 """Plain float32 reference of the served decoder and of sampled
 speculative verification, independent of the program.
 
-``hidden`` runs the published architecture (RMSNorm, rotary attention with
-grouped KV heads, SwiGLU MLP) in float32 at ``highest`` matmul precision,
-layer by layer, with each layer's weights regenerated from the seed by
-``bench/weights.py``: nothing is taken from the program.  With ``fp8=True``
-every matmul operand is rounded to float8 (e4m3, scaled per row / per
-output channel) first: the control, one precision below the bf16 the
-configurations state.
+``hidden`` runs the published architecture (each layer is the ``layer``
+of the section's module in ``bench/archs/``) in float32 at ``highest``
+matmul precision, layer by layer, with each layer's weights regenerated
+from the seed by the same module: nothing is taken from the program.  With
+``fp8=True`` every matmul operand is rounded to float8 (e4m3, scaled per
+row / per output channel) first: the control, one precision below the bf16
+the configurations state.
 
 The sampled verify step draws its noise from a key made of the sum of the
 pool's ``n_generated`` before the step: ``split(PRNGKey(sum), 3)`` gives the
@@ -24,6 +24,7 @@ perturbed reference logit lies below the best one.
 from __future__ import annotations
 
 import functools
+import json
 import math
 from dataclasses import dataclass
 from typing import Dict, List, Sequence
@@ -32,29 +33,30 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.weights import global_weights, layer_weights
+from bench import archs
+from bench.weights import global_weights
 
 E4M3_MAX = 448.0
 
 
-def _fp8(x, axis):
+def round_fp8(x, axis):
     """Round ``x`` to float8 e4m3 with one scale per slice along ``axis``."""
     s = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / E4M3_MAX
     s = jnp.where(s > 0, s, 1.0)
     return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def _mm(eq, x, w, fp8, w_axis):
+def mm(eq, x, w, fp8, w_axis):
     if fp8:
-        x, w = _fp8(x, -1), _fp8(w, w_axis)
+        x, w = round_fp8(x, -1), round_fp8(w, w_axis)
     return jnp.einsum(eq, x, w, precision=jax.lax.Precision.HIGHEST)
 
 
-def _rms(x, g, eps):
+def rms(x, g, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * g
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     hd = x.shape[-1]
     freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
     ang = pos[..., None].astype(jnp.float32) * freqs
@@ -63,72 +65,53 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _layer(w, h, m, fp8):
-    """One decoder layer over ``h [R, L, d]`` (positions 0..L-1, causal)."""
-    R, L, _ = h.shape
-    H, KVH, hd = m["n_heads"], m["n_kv_heads"], m["head_dim"]
-    eps = m["norm_eps"]
-    pos = jnp.arange(L)[None, :]
-    x = _rms(h, w["attn_norm"], eps)
-    q = _rope(_mm("rld,dhk->rlhk", x, w["wq"], fp8, 0), pos, m["rope_theta"])
-    k = _rope(_mm("rld,dhk->rlhk", x, w["wk"], fp8, 0), pos, m["rope_theta"])
-    v = _mm("rld,dhk->rlhk", x, w["wv"], fp8, 0)
-    G = H // KVH
-    qg = q.reshape(R, L, KVH, G, hd)
-    if fp8:
-        qg, k, v = _fp8(qg, -1), _fp8(k, -1), _fp8(v, 1)
-    sc = jnp.einsum("rqkgh,rskh->rkgqs", qg, k,
-                    precision=jax.lax.Precision.HIGHEST) / math.sqrt(hd)
-    causal = jnp.arange(L)[:, None] >= jnp.arange(L)[None, :]
-    sc = jnp.where(causal, sc, -jnp.inf)
-    p = jax.nn.softmax(sc, axis=-1)
-    if fp8:
-        p = _fp8(p, -1)
-    o = jnp.einsum("rkgqs,rskh->rqkgh", p, v,
-                   precision=jax.lax.Precision.HIGHEST).reshape(R, L, H, hd)
-    h = h + _mm("rlhk,hkd->rld", o.reshape(R, L, H, hd), w["wo"], fp8, (0, 1))
-    x = _rms(h, w["mlp_norm"], eps)
-    g = _mm("rld,df->rlf", x, w["w_gate"], fp8, 0)
-    u = _mm("rld,df->rlf", x, w["w_up"], fp8, 0)
-    return h + _mm("rlf,fd->rld", jax.nn.silu(g) * u, w["w_down"], fp8, 0)
-
-
 def _f32(tree):
     return jax.tree.map(lambda a: a.astype(jnp.float32), tree)
 
 
+def _key(m: Dict) -> str:
+    return json.dumps(m, sort_keys=True)
+
+
 @functools.lru_cache(maxsize=None)
-def _jits(m_items, fp8: bool, dtype):
+def _jits(m_key: str, fp8: bool, dtype):
     """Jitted weight generators and layer of one model shape (cached so
-    that repeated runs in one process compile once)."""
-    m = dict(m_items)
-    return (jax.jit(lambda k: global_weights(k, m, dtype)),
-            jax.jit(lambda k, l: layer_weights(k, m, l, dtype)),
-            jax.jit(lambda w, h: _layer(_f32(w), h, m, fp8)))
+    that repeated runs in one process compile once): the global leaves,
+    each layer's kind, a layer generator per kind, and the layer."""
+    m = json.loads(m_key)
+    A = archs.of(m)
+    kinds = archs.layer_kinds(A, m)
+
+    def gen(kind):
+        extra = () if kind is None else (kind,)
+        return jax.jit(lambda k, l: A.layer_weights(k, m, l, dtype, *extra))
+    return (jax.jit(lambda k: global_weights(k, m, dtype)), kinds,
+            {kind: gen(kind) for kind in set(kinds)},
+            jax.jit(lambda w, h: A.layer(_f32(w), h, m, fp8)))
 
 
 def hidden(mkey, m: Dict, tokens: np.ndarray, fp8: bool = False,
            dtype=jnp.bfloat16) -> jax.Array:
     """Final-norm hidden states ``[R, L, d]`` of token rows ``[R, L]``;
     the weights are the served ones (``dtype``), widened to float32."""
-    glob_fn, gen, step = _jits(tuple(sorted(m.items())), fp8, jnp.dtype(dtype))
+    glob_fn, kinds, gens, step = _jits(_key(m), fp8, jnp.dtype(dtype))
     glob = _f32(glob_fn(mkey))
     h = jnp.take(glob["embed"], jnp.asarray(tokens), axis=0)
-    for l in range(m["n_layers"]):
-        h = step(gen(mkey, l), h)
-    return _rms(h, glob["final_norm"], m["norm_eps"])
+    for l, kind in enumerate(kinds):
+        h = step(gens[kind](mkey, l), h)
+    return rms(h, glob["final_norm"], m["norm_eps"])
 
 
 def _globals(mkey, m: Dict, dtype) -> Dict:
     """The global leaves, generated under jit as the program's tree is."""
-    return _jits(tuple(sorted(m.items())), False, jnp.dtype(dtype))[0](mkey)
+    return _jits(_key(m), False, jnp.dtype(dtype))[0](mkey)
 
 
 def logits(mkey, m: Dict, h: jax.Array, fp8: bool = False,
            dtype=jnp.bfloat16) -> jax.Array:
     """Logits ``[..., V_padded]`` of hidden rows, padded ids at -1e30."""
     table = _globals(mkey, m, dtype)["unembed"].astype(jnp.float32)
-    z = _mm("...d,vd->...v", h, table, fp8, 1)
+    z = mm("...d,vd->...v", h, table, fp8, 1)
     return jnp.where(jnp.arange(table.shape[0]) < m["vocab_size"], z, -1e30)
 
 

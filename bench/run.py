@@ -51,7 +51,7 @@ def main(argv=None, root: str = ROOT, require_tpu: bool = True,
     spec = H.load_json(os.path.join(root, "BENCHMARK.json"))
     cell = H.find_cell(spec, args.workload)
     bdir = H.bench_dir(root)
-    conf = H.load_json(os.path.join(bdir, "configs", cell["config"] + ".json"))
+    conf = H.load_config(root, cell["config"])
     mix = Mix.load(os.path.join(bdir, "traffic", cell["traffic"] + ".json"),
                    cell["traffic"])
     limits = H.load_limits(root, cell["name"])

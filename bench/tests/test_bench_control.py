@@ -24,7 +24,7 @@ LONGOUT = {"kind": "open", "rate_per_s": 4.0, "cv": 1.0, "warm_in_s": 0.5,
 def test_fp8_control_fails_where_the_program_passes(tmp_path):
     root = make_root(tmp_path, mixes={"longout": LONGOUT},
                      config=dict(TINY, sampling={"temperature": 0.2}))
-    conf = H.load_json(os.path.join(root, "bench", "configs", "tiny.json"))
+    conf = H.load_config(root, "tiny")
     mix = Mix.load(os.path.join(root, "bench", "traffic", "longout.json"),
                    "longout")
     seed = 4242

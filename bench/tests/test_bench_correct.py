@@ -64,7 +64,7 @@ FAULTS = {"none": None, "token_altered": _token_altered,
 @pytest.fixture(scope="module")
 def engine(tmp_path_factory):
     root = make_root(tmp_path_factory.mktemp("correct"))
-    conf = H.load_json(os.path.join(root, "bench", "configs", "tiny.json"))
+    conf = H.load_config(root, "tiny")
     mix = Mix.load(os.path.join(root, "bench", "traffic", "tinychat.json"),
                    "tinychat")
     with JaxConfig():

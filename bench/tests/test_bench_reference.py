@@ -3,6 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import archs
 from bench import reference as ref
 from bench import weights as W
 from bench.harness import model_configs
@@ -13,7 +14,8 @@ def test_program_layout_equals_per_layer_regeneration():
     m = TINY["target"]
     mk = W.model_key(2**32 + 9, 0)
     tree = jax.jit(lambda k: W.program_params(k, m, jnp.bfloat16))(mk)
-    gen = jax.jit(lambda k, l: W.layer_weights(k, m, l, jnp.bfloat16))
+    gen = jax.jit(lambda k, l: archs.of(m).layer_weights(
+        k, m, l, jnp.bfloat16))
     for l in range(m["n_layers"]):
         one = gen(mk, l)
         for name, leaf in one.items():

@@ -15,12 +15,14 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 TINY = {
     "name": "tiny", "arch": None, "source": "test", "deployment": "test",
     "dtype": "float32",
-    "target": {"n_layers": 2, "d_model": 128, "d_ff": 256, "vocab_size": 500,
-               "vocab_padded": 512, "n_heads": 4, "n_kv_heads": 2,
-               "head_dim": 32, "rope_theta": 1e6, "norm_eps": 1e-6},
-    "draft": {"n_layers": 1, "d_model": 64, "d_ff": 128, "vocab_size": 500,
-              "vocab_padded": 512, "n_heads": 2, "n_kv_heads": 2,
-              "head_dim": 32, "rope_theta": 1e6, "norm_eps": 1e-6},
+    "target": {"model": "dense_gqa", "n_layers": 2, "d_model": 128,
+               "d_ff": 256, "vocab_size": 500, "vocab_padded": 512,
+               "n_heads": 4, "n_kv_heads": 2, "head_dim": 32,
+               "rope_theta": 1e6, "norm_eps": 1e-6},
+    "draft": {"model": "dense_gqa", "n_layers": 1, "d_model": 64,
+              "d_ff": 128, "vocab_size": 500, "vocab_padded": 512,
+              "n_heads": 2, "n_kv_heads": 2, "head_dim": 32,
+              "rope_theta": 1e6, "norm_eps": 1e-6},
     "reduced": [], "assumed": {},
     "serving": {"slots": 4, "cache_len": 128, "block_size": 16,
                 "token_budget": 64, "chunk": 32},
@@ -43,13 +45,17 @@ TINY_CHAT = {
 }
 
 
-def make_root(tmp, mixes=None, config=None) -> str:
+def make_root(tmp, mixes=None, config=None, files=None) -> str:
     """A checkout root under ``tmp`` with the tiny cell ``tiny.tinychat``
     and a cell ``tiny.<name>`` for each further mix: each cell is new
-    files (mix, limits) and new ``BENCHMARK.json`` entries only."""
+    files (mix, limits) and new ``BENCHMARK.json`` entries only.
+    ``files`` maps a path under the root to the file of this repo copied
+    there (an architecture module, say)."""
     root = os.path.join(str(tmp), "root")
     shutil.copytree(os.path.join(REPO, "bench"), os.path.join(root, "bench"),
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    for dest, src in (files or {}).items():
+        shutil.copy(os.path.join(REPO, src), os.path.join(root, dest))
     with open(os.path.join(root, "bench", "configs", "tiny.json"), "w") as f:
         json.dump(config or TINY, f)
     mixes = dict(mixes or {}, tinychat=TINY_CHAT)

@@ -2,24 +2,24 @@
 serves them in, and regenerated layer by layer for the reference.
 
 Every leaf is ``normal(key, shape) * scale`` (or ones for norm gains) with
-its own key: ``fold_in(fold_in(fold_in(seed_key, model), leaf), layer)``.
-The scales follow the program's default initialiser (embedding tables
-0.02, every other matrix 1/sqrt of its first unstacked dimension), so the
-served model behaves like the one the program's own tests build.  The
-program gets the whole tree from one jitted call, in bf16, on the device;
-the reference rebuilds any one layer from the same keys.
+its own key: ``fold_in(fold_in(fold_in(seed_key, model), leaf), layer)``,
+``leaf`` being the name's place in the architecture module's ``LEAVES``
+(``bench/archs/``).  The scales follow the program's default initialiser
+(embedding tables 0.02, every other matrix 1/sqrt of its first unstacked
+dimension), so the served model behaves like the one the program's own
+tests build.  The program gets the whole tree from one jitted call, in
+bf16, on the device; the reference rebuilds any one layer from the same
+keys.
 """
 from __future__ import annotations
 
 import math
-from typing import Dict, Tuple
+from typing import Dict, Sequence
 
 import jax
 import jax.numpy as jnp
 
-LEAVES = ("embed", "unembed", "final_norm", "attn_norm", "mlp_norm", "wq",
-          "wk", "wv", "wo", "w_gate", "w_up", "w_down")
-LAYER_LEAVES = LEAVES[3:]
+from bench import archs
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -29,28 +29,17 @@ def seed_key(seed: int) -> jax.Array:
                               (seed >> 32) & 0x7FFFFFFF)
 
 
-def global_shapes(m: Dict) -> Dict[str, Tuple[int, ...]]:
-    d, vp = m["d_model"], m["vocab_padded"]
-    return {"embed": (vp, d), "unembed": (vp, d), "final_norm": (d,)}
-
-
-def layer_shapes(m: Dict) -> Dict[str, Tuple[int, ...]]:
-    d, ff = m["d_model"], m["d_ff"]
-    H, KVH, hd = m["n_heads"], m["n_kv_heads"], m["head_dim"]
-    return {"attn_norm": (d,), "mlp_norm": (d,), "wq": (d, H, hd),
-            "wk": (d, KVH, hd), "wv": (d, KVH, hd), "wo": (H, hd, d),
-            "w_gate": (d, ff), "w_up": (d, ff), "w_down": (ff, d)}
-
-
-def _leaf(key, name: str, shape, dtype):
+def leaf(key, name: str, shape, dtype):
+    """A leaf by the default rule: ones for a norm gain, else a normal draw
+    at 0.02 (embedding tables) or 1/sqrt of the first dimension."""
     if name.endswith("norm"):
         return jnp.ones(shape, dtype)
     scale = 0.02 if name in ("embed", "unembed") else 1.0 / math.sqrt(shape[0])
     return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
 
 
-def _key(model_key, name: str, layer=None):
-    k = jax.random.fold_in(model_key, LEAVES.index(name))
+def leaf_key(model_key, leaves: Sequence[str], name: str, layer=None):
+    k = jax.random.fold_in(model_key, leaves.index(name))
     return k if layer is None else jax.random.fold_in(k, layer)
 
 
@@ -59,23 +48,21 @@ def model_key(seed: int, model: int) -> jax.Array:
     return jax.random.fold_in(seed_key(seed), model)
 
 
-def layer_weights(mkey, m: Dict, layer, dtype=jnp.bfloat16) -> Dict:
-    """One layer's leaves (``layer`` may be traced)."""
-    return {n: _leaf(_key(mkey, n, layer), n, shp, dtype)
-            for n, shp in layer_shapes(m).items()}
-
-
 def global_weights(mkey, m: Dict, dtype=jnp.bfloat16) -> Dict:
-    return {n: _leaf(_key(mkey, n), n, shp, dtype)
-            for n, shp in global_shapes(m).items()}
+    A = archs.of(m)
+    return {n: leaf(leaf_key(mkey, A.LEAVES, n), n, shp, dtype)
+            for n, shp in A.global_shapes(m).items()}
 
 
 def program_params(mkey, m: Dict, dtype=jnp.bfloat16) -> Dict:
     """The program's tree: global leaves plus ``layers`` stacked on a
     leading axis, built one layer at a time (``lax.map``) so that no
     float32 copy of a stacked leaf is ever live."""
+    A = archs.of(m)
+    if hasattr(A, "program_params"):
+        return A.program_params(mkey, m, dtype)
     p = global_weights(mkey, m, dtype)
-    p["layers"] = jax.lax.map(lambda l: layer_weights(mkey, m, l, dtype),
+    p["layers"] = jax.lax.map(lambda l: A.layer_weights(mkey, m, l, dtype),
                               jnp.arange(m["n_layers"]))
     return p
 
